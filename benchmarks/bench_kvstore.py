@@ -105,13 +105,13 @@ def main():
     key = jax.random.PRNGKey(1)
     k1, k2, k3 = jax.random.split(key, 3)
     q = jax.random.normal(k1, (B, 1, H, hd))
-    kc = jax.random.normal(k2, (B, W, KVH, hd))
-    vc = jax.random.normal(k3, (B, W, KVH, hd))
+    kc = jax.random.normal(k2, (B, KVH, W, hd))
+    vc = jax.random.normal(k3, (B, KVH, W, hd))
     lengths = jnp.full((B,), W, jnp.int32)
     nb = W // bl
     tables = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
-    pool_k = kc.reshape(B * nb, bl, KVH, hd)
-    pool_v = vc.reshape(B * nb, bl, KVH, hd)
+    pool_k = kc.reshape(B, KVH, nb, bl, hd)
+    pool_v = vc.reshape(B, KVH, nb, bl, hd)
     t0 = time.perf_counter()
     jax.block_until_ready(decode_attention_pallas(
         q, kc, vc, lengths, block_k=bl, interpret=True))

@@ -9,7 +9,10 @@ Grid: (batch, q_heads, num_q_blocks, num_kv_blocks) with the KV-block axis
 innermost and sequential ("arbitrary"), accumulating into VMEM scratch.
 Causal block-skipping uses @pl.when so fully-masked KV blocks do no MXU
 work. GQA is expressed in the K/V index_map (kv_head = q_head // G) so K/V
-tiles are fetched once per KV head, not once per Q head.
+tiles are fetched once per KV head, not once per Q head.  The wrapper
+lays K/V out head-major, ``(B, KVH, S, hd)`` (an O(S) transpose of the
+chunk's own projections), so each K/V tile's last two dims are
+``(block_k, hd)`` as Mosaic requires.
 
 Block sizes default to (128, head_dim): MXU-aligned when head_dim is a
 multiple of 128; head_dim=64 models still lower (Mosaic pads lanes).
@@ -24,10 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax < 0.5 names this TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -58,8 +57,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)         # (block_q, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)      # (block_k, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)         # (block_k, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (bq, bk)
@@ -111,6 +110,8 @@ def flash_attention_pallas(
     nq, nk = Sq // block_q, Skv // block_k
 
     qt = q.transpose(0, 2, 1, 3)                     # (B, H, Sq, hd)
+    kt = k.transpose(0, 2, 1, 3)                     # (B, KVH, Skv, hd)
+    vt = v.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
@@ -122,10 +123,10 @@ def flash_attention_pallas(
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd),
                          lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, qi, ki: (b, ki, h // G, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, h, qi, ki: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, h, qi, ki: (b, h // G, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd),
                                lambda b, h, qi, ki: (b, h, qi, 0)),
@@ -135,9 +136,9 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(qt, k, v)
+    )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -24,10 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names this TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, h0_ref,
                  y_ref, hout_ref, h_scr, *, time_blk: int,
@@ -111,7 +107,7 @@ def mamba_scan_pallas(
             jax.ShapeDtypeStruct((B, C, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((channel_blk, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x.astype(jnp.float32), dt.astype(jnp.float32),

@@ -57,7 +57,7 @@ def mamba_scan_ref(x, dt, b_ssm, c_ssm, a, d, h0):
 
 def decode_attention_ref(
     q: jax.Array,        # (B, 1, H, hd) or (B, H, hd)
-    k_cache: jax.Array,  # (B, W, KVH, hd)
+    k_cache: jax.Array,  # (B, KVH, W, hd)
     v_cache: jax.Array,
     lengths: jax.Array,  # (B,)
     *,
@@ -68,14 +68,14 @@ def decode_attention_ref(
         q = q[:, 0]
         squeeze = True
     B, H, hd = q.shape
-    W, KVH = k_cache.shape[1], k_cache.shape[2]
+    KVH, W = k_cache.shape[1], k_cache.shape[2]
     G = H // KVH
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     qf = q.astype(jnp.float32).reshape(B, KVH, G, hd)
-    s = jnp.einsum("bkgd,bwkd->bkgw", qf, k_cache.astype(jnp.float32)) * scale
+    s = jnp.einsum("bkgd,bkwd->bkgw", qf, k_cache.astype(jnp.float32)) * scale
     valid = jnp.arange(W)[None] < lengths[:, None]
     s = jnp.where(valid[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgw,bwkd->bkgd", p, v_cache.astype(jnp.float32))
+    o = jnp.einsum("bkgw,bkwd->bkgd", p, v_cache.astype(jnp.float32))
     o = o.reshape(B, H, hd).astype(q.dtype)
     return o[:, None] if squeeze else o

@@ -43,8 +43,10 @@ from repro.kvstore.base import BlockLedger, KVStoreError, LineCosts
 from repro.models import init_state
 from repro.models.blocks import layer_specs, plan_segments
 
-#: attention-state keys indexed by KV line (axis 2 of the stacked leaf)
-LINE_KEYS = ("k", "v", "c_kv", "k_rope")
+#: attention-state keys indexed by KV line, mapped to the line axis of
+#: the stacked (repeat-dim-first) leaf: head-major K/V ``(R, B, KVH, W,
+#: hd)``, MLA latents ``(R, B, W, d)``
+LINE_AXIS = {"k": 3, "v": 3, "c_kv": 2, "k_rope": 2}
 #: attention-state keys written once at prefill (enc-dec cross caches)
 STATIC_KEYS = ("xk", "xv")
 
@@ -57,14 +59,21 @@ def pick_block_lines(kv_capacity: int, requested: int = 16) -> int:
     return b
 
 
+def line_index(slot, pos, axis: int) -> tuple:
+    """Index of lines ``pos`` (array or slice) of batch row ``slot`` in a
+    stacked leaf whose line axis is ``axis``."""
+    return (slice(None), slot) + (slice(None),) * (axis - 2) + (pos,)
+
+
 # jitted copy primitives for the mirror hot path: slot indices and row
 # positions are traced (one compile per (shape, n_rows), reused every
 # step); the destination buffer is donated so the update is in place.
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _copy_rows(dst, src, dst_slot, src_slot, pos):
-    return dst.at[:, dst_slot, pos].set(src[:, src_slot, pos])
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("axis",))
+def _copy_rows(dst, src, dst_slot, src_slot, pos, axis):
+    return dst.at[line_index(dst_slot, pos, axis)].set(
+        src[line_index(src_slot, pos, axis)])
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -75,9 +84,10 @@ def _copy_entry(dst, src, dst_slot, src_slot):
 # intra-store gather for prefix adoption: source rows live in *other*
 # slots' windows (one per shared block), so the row vector carries its
 # own per-row slot index; not donated — src and dst are the same buffer
-@jax.jit
-def _gather_rows(arr, dst_slot, src_slots, src_pos, dst_pos):
-    return arr.at[:, dst_slot, dst_pos].set(arr[:, src_slots, src_pos])
+@functools.partial(jax.jit, static_argnames=("axis",))
+def _gather_rows(arr, dst_slot, src_slots, src_pos, dst_pos, axis):
+    return arr.at[line_index(dst_slot, dst_pos, axis)].set(
+        arr[line_index(src_slots, src_pos, axis)])
 
 
 def _colocated(a, b) -> bool:
@@ -117,12 +127,13 @@ class PagedStore:
         self.slot_rid: Dict[int, int] = {}
         self.rid_slot: Dict[int, int] = {}
         # leaf classification: (segment index, part key, leaf key, kind)
+        # with kind "line" / "static" / "recurrent"
         self._paths: List[Tuple[int, str, str, str]] = []
         for i, seg in enumerate(plan_segments(layer_specs(cfg))):
             for j, spec in enumerate(seg.specs):
                 for key in self.state["layers"][i][f"p{j}"]:
                     if spec.block == "attn":
-                        kind = "line" if key in LINE_KEYS else "static"
+                        kind = "line" if key in LINE_AXIS else "static"
                     else:
                         kind = "recurrent"
                     self._paths.append((i, f"p{j}", key, kind))
@@ -151,10 +162,10 @@ class PagedStore:
         return list(range(lo, lo + self.blocks_per_slot))
 
     def line_block_table(self, rid: int) -> List[int]:
-        """Physical *line-block* ids of ``rid`` in pool numbering (the
-        dense caches reshaped to ``(num_slots * kv_capacity/block_lines,
-        block_lines, ...)``), the table the paged decode kernel gathers
-        through."""
+        """Physical *line-block* ids of ``rid`` in pool numbering (block
+        ``slot * kv_capacity/block_lines + j`` is line block ``j`` of the
+        slot's window, see :meth:`pool_view`), the table the paged decode
+        kernel gathers through."""
         off = 1 if self._has_fixed else 0
         out = []
         for b in self.ledger.tables[rid]:
@@ -180,12 +191,13 @@ class PagedStore:
         return out
 
     def pool_view(self, arr: jnp.ndarray) -> jnp.ndarray:
-        """Reshape one request-batched cache leaf ``(B, W, ...)`` into the
-        block pool ``(B * W/block_lines, block_lines, ...)`` addressed by
-        :meth:`line_block_table`."""
-        B, W = arr.shape[:2]
-        return arr.reshape((B * (W // self.block_lines), self.block_lines)
-                           + arr.shape[2:])
+        """Reshape one request-batched head-major cache leaf ``(B, KVH, W,
+        hd)`` into the block pool ``(B, KVH, W/block_lines, block_lines,
+        hd)`` addressed by :meth:`line_block_table` (a reshape, no
+        copy)."""
+        B, KVH, W, hd = arr.shape
+        return arr.reshape(B, KVH, W // self.block_lines, self.block_lines,
+                           hd)
 
     # -- ledger ops (slot-affine) ----------------------------------------------
     def alloc(self, rid: int, slot: int, lines: int,
@@ -315,12 +327,14 @@ class PagedStore:
                 from repro.meshserve import collectives
                 src = collectives.device_transfer(src, dst)
             if kind == "line":
-                h = min(hi, src.shape[2], dst.shape[2])
+                ax = LINE_AXIS[key]
+                h = min(hi, src.shape[ax], dst.shape[ax])
                 l = min(lo, h)
                 if h <= l:
                     continue
                 self.state["layers"][i][pj][key] = dst.at[
-                    :, slot, l:h].set(src[:, src_slot, l:h])
+                    line_index(slot, slice(l, h), ax)].set(
+                        src[line_index(src_slot, slice(l, h), ax)])
             else:
                 self.state["layers"][i][pj][key] = dst.at[:, slot].set(
                     src[:, src_slot])
@@ -388,12 +402,14 @@ class PagedStore:
                 continue
             if n_rows <= 0:
                 continue
-            cap = dst_arr.shape[2]
+            ax = LINE_AXIS[key]
+            cap = dst_arr.shape[ax]
             pos = jnp.asarray([p % cap for p in range(lo, hi)], jnp.int32)
             self.state["layers"][i][pj][key] = (
-                _copy_rows(dst_arr, src_arr, d_slot, s_slot, pos) if local
-                else collectives.pull_rows(dst_arr, src_arr,
-                                           dst_slot, src_slot, pos))
+                _copy_rows(dst_arr, src_arr, d_slot, s_slot, pos, axis=ax)
+                if local
+                else collectives.pull_rows(dst_arr, src_arr, dst_slot,
+                                           src_slot, pos, axis=ax))
         return self.costs.mirror_bytes(max(0, to_line - from_line))
 
     # -- prefix adoption (one-time window fill) --------------------------------
@@ -432,5 +448,6 @@ class PagedStore:
             arr = self.state["layers"][i][pj][key]
             self.state["layers"][i][pj][key] = _gather_rows(
                 arr, d_slot, jnp.asarray(src_slots),
-                jnp.asarray(src_pos), jnp.asarray(dst_pos))
+                jnp.asarray(src_pos), jnp.asarray(dst_pos),
+                axis=LINE_AXIS[key])
         return self.costs.line_bytes * n_lines

@@ -1,8 +1,10 @@
 """Serving launcher: drive a live-engine cluster under any registered
 scheduling policy through the ``repro.api.serve`` facade.
 
-CPU-runnable with reduced configs (default); on a real TPU fleet the same
-code paths run the full configs with the TP specs from launch/specs.py.
+CPU-runnable with reduced configs (default); ``--full-config`` serves the
+published widths (``starcoder2-3b``, the default arch, fits one v5e chip
+in bf16).  The persistent compilation cache is placed by
+``repro.launch.cache.use_compile_cache``.
 
 Traffic comes from the shared workload layer (``repro.workloads``): the
 default is the legacy batch-at-t=0 request set, but ``--arrival poisson``
@@ -12,7 +14,7 @@ keeps ``--concurrency`` requests in flight.  ``--slo-ttft/--slo-tbt``
 add SLO attainment and goodput to the report.
 
 Usage:
-  PYTHONPATH=src python -m repro.launch.serve --arch phi3-medium-14b \
+  PYTHONPATH=src python -m repro.launch.serve --arch starcoder2-3b \
       --instances 4 --requests 16 \
       [--policy accellm|vllm|splitwise|sarathi|ulb] \
       [--no-redundancy] [--workload mixed] [--arrival poisson --rate 0.5 \
@@ -31,6 +33,7 @@ from repro.api import ServeSpec, serve
 from repro.configs import list_archs
 from repro.fleet import (FixedFleet, PoissonDegradations, PoissonFailures,
                          load_fleet_trace)
+from repro.launch.cache import use_compile_cache
 from repro.scheduling.registry import policy_names
 from repro.workloads import (SLO, TABLE2, Batch, Bursty, ClosedLoop,
                              DiurnalRamp, Poisson, PrefixReuse, TableLengths,
@@ -84,7 +87,7 @@ def build_fleet(args):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="phi3-medium-14b", choices=list_archs())
+    ap.add_argument("--arch", default="starcoder2-3b", choices=list_archs())
     ap.add_argument("--policy", default="accellm", choices=policy_names(),
                     help="scheduling policy (shared kernel; the same names "
                          "drive the simulator)")
@@ -168,6 +171,7 @@ def main():
     ap.add_argument("--full-config", action="store_true",
                     help="use the full (non-reduced) architecture")
     args = ap.parse_args()
+    use_compile_cache()
 
     reuse = (PrefixReuse(pool=args.prefix_pool, reuse=args.prefix_reuse,
                          prefix_len=args.prefix_len)

@@ -246,11 +246,15 @@ def state_pspecs(cfg: ModelConfig, state_shape, shape: InputShape,
         bdim = 0 if is_enc else 1
         if batch_ok and nd > bdim and _div(shape_[bdim], b_n):
             spec[bdim] = b_ax
-        seq_dims = {"k": 2, "v": 2, "c_kv": 2, "k_rope": 2, "xk": 2, "xv": 2}
+        # stacked layouts: head-major k/v (R, B, KVH, W, hd); cross
+        # caches (R, B, S, KVH, hd); MLA latents (R, B, W, d)
+        seq_dims = {"k": 3, "v": 3, "c_kv": 2, "k_rope": 2, "xk": 2, "xv": 2}
+        head_dims = {"k": 2, "v": 2, "xk": 3, "xv": 3}
         tail = ps.rsplit("/", 1)[-1]
-        if not batch_ok and tail in seq_dims and nd > 2 \
-                and _div(shape_[2], b_n):
-            spec[2] = b_ax                      # shard KV seq over data
+        sd = seq_dims.get(tail)
+        if not batch_ok and sd is not None and nd > sd \
+                and _div(shape_[sd], b_n):
+            spec[sd] = b_ax                     # shard KV seq over data
         if tail in ("c_kv", "k_rope") and nd > 2 and spec[2] is None \
                 and _div(shape_[2], model_n):
             # MLA latent cache: shard the SEQUENCE dim over the model axis
@@ -261,9 +265,9 @@ def state_pspecs(cfg: ModelConfig, state_shape, shape: InputShape,
             # all-reduces.
             spec[2] = "model"
         # model-parallel inner dims where divisible
-        if tail in ("k", "v", "xk", "xv") and nd >= 4 \
-                and _div(shape_[3], model_n):
-            spec[3] = "model"                   # kv heads
+        hd_ = head_dims.get(tail)
+        if hd_ is not None and nd >= 4 and _div(shape_[hd_], model_n):
+            spec[hd_] = "model"                 # kv heads
         if tail == "ssm" and nd >= 3 and _div(shape_[2], model_n):
             spec[2] = "model"                   # mamba d_in
         if tail == "conv" and nd >= 4 and _div(shape_[3], model_n):

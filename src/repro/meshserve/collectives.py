@@ -26,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.kvstore.paged import line_index
+
 
 @dataclass
 class TransferStats:
@@ -74,7 +76,11 @@ def device_transfer(x, like):
         with jax.transfer_guard_device_to_host("disallow"):
             out = jax.device_put(x, target)
             out.block_until_ready()
-    except Exception:
+    except jax.errors.JaxRuntimeError as e:
+        # only the guard's own refusal falls back; any other failure is
+        # a real fault and propagates
+        if "Disallowed device-to-host transfer" not in str(e):
+            raise
         STATS.host_copies += 1
         out = jax.device_put(np.asarray(x), target)
     else:
@@ -89,14 +95,14 @@ def device_transfer(x, like):
 # is the only inter-slice hop.
 
 
-@jax.jit
-def _gather_rows(src, slot, pos):
-    return src[:, slot, pos]
+@functools.partial(jax.jit, static_argnames=("axis",))
+def _gather_rows(src, slot, pos, axis):
+    return src[line_index(slot, pos, axis)]
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_rows(dst, rows, slot, pos):
-    return dst.at[:, slot, pos].set(rows)
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("axis",))
+def _scatter_rows(dst, rows, slot, pos, axis):
+    return dst.at[line_index(slot, pos, axis)].set(rows)
 
 
 @jax.jit
@@ -109,12 +115,13 @@ def _scatter_entry(dst, rows, slot):
     return dst.at[:, slot].set(rows)
 
 
-def pull_rows(dst, src, dst_slot: int, src_slot: int, pos):
+def pull_rows(dst, src, dst_slot: int, src_slot: int, pos, axis: int):
     """Cross-slice form of the mirror's row copy: ``src``'s KV rows
-    ``pos`` of ``src_slot`` land in ``dst``'s ``dst_slot``."""
-    rows = _gather_rows(src, jnp.int32(src_slot), pos)
+    ``pos`` (on line ``axis``) of ``src_slot`` land in ``dst``'s
+    ``dst_slot``."""
+    rows = _gather_rows(src, jnp.int32(src_slot), pos, axis=axis)
     rows = device_transfer(rows, dst)
-    return _scatter_rows(dst, rows, jnp.int32(dst_slot), pos)
+    return _scatter_rows(dst, rows, jnp.int32(dst_slot), pos, axis=axis)
 
 
 def pull_entry(dst, src, dst_slot: int, src_slot: int):

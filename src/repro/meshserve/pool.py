@@ -6,10 +6,11 @@ shards exactly the dims training would (column-parallel q/k/v and FFN
 up, row-parallel output projections, vocab-sharded embed/lm_head).
 
 ``shard_store`` places a live :class:`repro.kvstore.PagedStore`'s state
-arrays on the slice: the attention K/V leaves — stacked layout
-``(R, B, W, KVH, hd)`` — shard their KV-head dim over the ``model`` axis
-when divisible (the paged decode kernel gathers per head, so each shard
-reads only its own heads' line blocks); everything else replicates.
+arrays on the slice: the attention K/V leaves — stacked head-major
+layout ``(R, B, KVH, W, hd)``, enc-dec cross caches ``(R, B, S, KVH,
+hd)`` — shard their KV-head dim over the ``model`` axis when divisible
+(the paged decode kernel gathers per head, so each shard reads only its
+own heads' line blocks); everything else replicates.
 Block tables stay host-side numpy and are replicated into each dispatch,
 exactly as on a single device.
 """
@@ -21,10 +22,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.specs import param_pspecs
 from repro.meshserve.topology import MeshSlice
 
-#: store leaves whose dim 3 is the KV head dim of the stacked
-#: ``(R, B, W, KVH, hd)`` layout (k/v line caches + enc-dec cross caches)
-_HEAD_SHARDED = ("k", "v", "xk", "xv")
-_HEAD_DIM = 3
+#: store leaves sharded on their KV head dim, mapped to that dim: the
+#: head-major k/v line caches and the enc-dec cross caches
+_HEAD_DIM = {"k": 2, "v": 2, "xk": 3, "xv": 3}
 
 
 def shard_params(cfg, params, sl: MeshSlice):
@@ -42,9 +42,9 @@ def shard_store(store, sl: MeshSlice) -> None:
     for i, pj, key, kind in store._paths:
         arr = store.state["layers"][i][pj][key]
         spec = [None] * arr.ndim
-        if (key in _HEAD_SHARDED and arr.ndim > _HEAD_DIM + 1
-                and arr.shape[_HEAD_DIM] % sl.tp == 0):
-            spec[_HEAD_DIM] = "model"
+        dim = _HEAD_DIM.get(key)
+        if dim is not None and arr.shape[dim] % sl.tp == 0:
+            spec[dim] = "model"
         store.state["layers"][i][pj][key] = jax.device_put(
             arr, NamedSharding(sl.mesh, P(*spec)))
     if "enc_out" in store.state:

@@ -25,23 +25,33 @@ Array = jax.Array
 
 NEG_INF = -1e30
 
-# Kernel backend switch: "jnp" (portable, used on CPU + dry-run), "pallas"
-# (TPU target; compiled Mosaic kernels) or "auto" (pallas iff on TPU).
+# Kernel backend switch: "jnp" (portable), "pallas" (compiled Mosaic
+# kernels, TPU only), "interpret" (the Pallas kernel bodies in interpret
+# mode, for CPU tests) or "auto" (pallas on TPU, jnp elsewhere).
 KERNEL_BACKEND = "auto"
 
 
 def set_kernel_backend(name: str):
     global KERNEL_BACKEND
-    assert name in ("jnp", "pallas", "auto")
+    assert name in ("jnp", "pallas", "interpret", "auto")
     KERNEL_BACKEND = name
 
 
-def _use_pallas() -> bool:
-    if KERNEL_BACKEND == "pallas":
-        return True
+def kernel_backend() -> str:
+    """The resolved backend: "jnp", "pallas" or "interpret"."""
     if KERNEL_BACKEND == "auto":
-        return jax.default_backend() == "tpu"
-    return False
+        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+    return KERNEL_BACKEND
+
+
+def prefill_kernel(q_len: int, history: int = 0) -> str:
+    """Backend of the prefill attention over ``q_len`` new tokens after
+    ``history`` cached ones: the Pallas flash kernel takes a fresh span
+    of whole 128-token tiles, everything else the jnp scan."""
+    backend = kernel_backend()
+    if backend == "jnp" or history or q_len % 128:
+        return "jnp"
+    return backend
 
 
 # ---------------------------------------------------------------------------
@@ -170,9 +180,10 @@ class PagedDecode:
     request slot ``slots[i]`` of the (full, ``num_slots``-row) cache
     state, and its K/V is read back through ``tables[i]`` — physical
     line-block ids into the pool view of the cache
-    (``PagedStore.pool_view`` layout: the dense ``(B, W, ...)`` leaf
-    reshaped to ``(B * W/block_lines, block_lines, ...)``).  Replica and
-    free slots are simply absent from ``slots``, so they cost nothing.
+    (``PagedStore.pool_view`` layout: the head-major ``(B, KVH, W, hd)``
+    leaf reshaped to ``(B, KVH, W/block_lines, block_lines, hd)``, block
+    id ``slot * W/block_lines + j``).  Replica and free slots are simply
+    absent from ``slots``, so they cost nothing.
     """
 
     __slots__ = ("slots", "tables", "block_lines")
@@ -185,22 +196,22 @@ class PagedDecode:
 
 def decode_attention(
     q: Array,            # (B, 1, H, hd)
-    k_cache: Array,      # (B, W, KVH, hd)
-    v_cache: Array,      # (B, W, KVH, hd)
+    k_cache: Array,      # (B, KVH, W, hd)
+    v_cache: Array,      # (B, KVH, W, hd)
     *,
     scale: float,
     valid: Array,        # (W,) bool or (B, W) bool — which slots are live
 ) -> Array:
     B, _, H, hd = q.shape
-    KVH = k_cache.shape[2]
+    KVH = k_cache.shape[1]
     G = H // KVH
     qf = q.astype(jnp.float32).reshape(B, KVH, G, hd)
-    s = jnp.einsum("bkgd,bwkd->bkgw", qf, k_cache.astype(jnp.float32)) * scale
+    s = jnp.einsum("bkgd,bkwd->bkgw", qf, k_cache.astype(jnp.float32)) * scale
     if valid.ndim == 1:
         valid = valid[None]
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgw,bwkd->bkgd", p, v_cache.astype(jnp.float32))
+    o = jnp.einsum("bkgw,bkwd->bkgd", p, v_cache.astype(jnp.float32))
     return o.reshape(B, 1, H, hd).astype(q.dtype)
 
 
@@ -209,26 +220,39 @@ def decode_attention(
 # ---------------------------------------------------------------------------
 
 
-def ring_write(cache: Array, values: Array, t: Array, capacity: int) -> Array:
-    """Write values (B, S, ...) at logical positions [t, t+S) modulo capacity.
+def ring_write(cache: Array, values: Array, t: Array, capacity: int,
+               axis: int = 1) -> Array:
+    """Write values at logical positions [t, t+S) modulo capacity along
+    the line ``axis`` of ``cache`` (``values`` has S lines on the same
+    axis; batch is axis 0 of both).
 
     ``t`` may be a scalar clock (shared by the batch — prefill) or a (B,)
     per-request clock (continuous batching decode)."""
-    S = values.shape[1]
+    S = values.shape[axis]
+    lead = (slice(None),) * axis
     if S >= capacity:
         # keep only the last `capacity` entries, already aligned to slots
-        vals = values[:, -capacity:]
+        vals = values[lead + (slice(S - capacity, None),)]
         pos = (t + S - capacity + jnp.arange(capacity)) % capacity
-        return cache.at[:, pos].set(vals)
+        return cache.at[lead + (pos,)].set(vals)
     if jnp.ndim(t) == 0:
         pos = (t + jnp.arange(S)) % capacity
-        return cache.at[:, pos].set(values)
+        return cache.at[lead + (pos,)].set(values)
 
     def write_one(c, val, tt):
         pos = (tt + jnp.arange(S)) % capacity
-        return c.at[pos].set(val)
+        return c.at[lead[1:] + (pos,)].set(val)
 
     return jax.vmap(write_one)(cache, values, t)
+
+
+def _write_kv(state: dict, k: Array, v: Array, t: Array) -> dict:
+    """Ring-write the (B, S, KVH, hd) K/V projections at [t, t+S) into
+    the head-major (B, KVH, W, hd) cache."""
+    cap = state["k"].shape[2]
+    return dict(state,
+                k=ring_write(state["k"], k.swapaxes(1, 2), t, cap, axis=2),
+                v=ring_write(state["v"], v.swapaxes(1, 2), t, cap, axis=2))
 
 
 def ring_valid(t_next: Array, capacity: int) -> Array:
@@ -289,33 +313,31 @@ def gqa_forward(
         # positions) plus the chunk itself; new rows land at [t, t+S).
         assert state is not None and causal
         k_all = jnp.concatenate(
-            [state["k"][:, :history].astype(k.dtype), k], axis=1)
+            [state["k"][:, :, :history].swapaxes(1, 2).astype(k.dtype), k],
+            axis=1)
         v_all = jnp.concatenate(
-            [state["v"][:, :history].astype(v.dtype), v], axis=1)
+            [state["v"][:, :, :history].swapaxes(1, 2).astype(v.dtype), v],
+            axis=1)
         out = flash_attention(q, k_all, v_all, causal=True, scale=scale,
                               window=window, q_offset=history)
         new_state = state
         if update_cache:
-            cap = state["k"].shape[1]
-            t0 = t if t is not None else jnp.int32(history)
-            new_state = dict(state)
-            new_state["k"] = ring_write(state["k"], k, t0, cap)
-            new_state["v"] = ring_write(state["v"], v, t0, cap)
+            new_state = _write_kv(state, k, v, t if t is not None
+                                  else jnp.int32(history))
     elif mode == "full":
-        if _use_pallas() and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0:
+        backend = prefill_kernel(S)
+        if backend != "jnp":
             from repro.kernels.flash_attention import flash_attention_pallas
             out = flash_attention_pallas(q, k, v, causal=causal, scale=scale,
-                                         window=window)
+                                         window=window,
+                                         interpret=backend == "interpret")
         else:
             out = flash_attention(q, k, v, causal=causal, scale=scale,
                                   window=window)
         new_state = state
         if update_cache and state is not None:
-            cap = state["k"].shape[1]
-            t0 = t if t is not None else jnp.int32(0)
-            new_state = dict(state)
-            new_state["k"] = ring_write(state["k"], k, t0, cap)
-            new_state["v"] = ring_write(state["v"], v, t0, cap)
+            new_state = _write_kv(state, k, v,
+                                  t if t is not None else jnp.int32(0))
     elif mode == "decode" and paged is not None:
         # paged hot path (ISSUE 5): the batch is compacted to the active
         # primary slots; the new K/V line scatters into the full cache at
@@ -329,25 +351,23 @@ def gqa_forward(
         # head dim to the slice's model axis so the per-head block gather
         # below stays shard-local (no-op without an active mesh)
         q = sharding.constrain(q, "batch", None, "model", None)
-        cap = state["k"].shape[1]
+        cap = state["k"].shape[2]
         pos = t % cap
-        kc = state["k"].at[paged.slots, pos].set(k[:, 0])
-        vc = state["v"].at[paged.slots, pos].set(v[:, 0])
+        kc = state["k"].at[paged.slots, :, pos].set(k[:, 0])
+        vc = state["v"].at[paged.slots, :, pos].set(v[:, 0])
         bl = paged.block_lines
-        pool_shape = (kc.shape[0] * (cap // bl), bl, kvh, hd)
+        pool_shape = (kc.shape[0], kvh, cap // bl, bl, hd)
         lengths = jnp.minimum(t + 1, cap)
         out = paged_decode_attention(
             q, kc.reshape(pool_shape), vc.reshape(pool_shape),
-            paged.tables, lengths, scale=scale, use_pallas=_use_pallas())
+            paged.tables, lengths, scale=scale, backend=kernel_backend())
         new_state = dict(state, k=kc, v=vc)
     elif mode == "decode":
         assert state is not None and t is not None
-        cap = state["k"].shape[1]
-        kc = ring_write(state["k"], k, t, cap)
-        vc = ring_write(state["v"], v, t, cap)
-        valid = ring_valid(t + S, cap)
-        out = decode_attention(q, kc, vc, scale=scale, valid=valid)
-        new_state = dict(state, k=kc, v=vc)
+        new_state = _write_kv(state, k, v, t)
+        valid = ring_valid(t + S, state["k"].shape[2])
+        out = decode_attention(q, new_state["k"], new_state["v"],
+                               scale=scale, valid=valid)
     else:
         raise ValueError(mode)
 

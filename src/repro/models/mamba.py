@@ -104,13 +104,15 @@ def mamba_forward(
         h0 = (state["ssm"] if state is not None
               else jnp.zeros((B, d_in, mc.d_state), jnp.float32))
 
-        from repro.models.attention import _use_pallas
-        if _use_pallas() and S % 256 == 0 and d_in % 128 == 0:
+        from repro.models.attention import kernel_backend
+        backend = kernel_backend()
+        if backend != "jnp" and S % 256 == 0 and d_in % 128 == 0:
             # fused Pallas selective scan: state stays in VMEM across the
             # whole sequence instead of an HBM round-trip per step
             # (§Perf iteration 8)
             from repro.kernels.mamba_scan import mamba_scan_pallas
-            y, hT = mamba_scan_pallas(x_c, dt, b_ssm, c_ssm, A, D, h0)
+            y, hT = mamba_scan_pallas(x_c, dt, b_ssm, c_ssm, A, D, h0,
+                                      interpret=backend == "interpret")
         else:
             def step(h, inp):
                 x_t, dt_t, b_t, c_t = inp

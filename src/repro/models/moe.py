@@ -28,15 +28,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro import sharding
 from repro.configs.base import ModelConfig
-
-# jax < 0.5 exposes shard_map under jax.experimental with a differently
-# named replication-check flag; newer releases promoted it to jax.shard_map
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
 from repro.models.common import KeyGen, dense_init, swiglu
 from repro.models.ffn import dense_ffn, init_dense_ffn
 
@@ -243,9 +234,9 @@ def moe_forward(cfg: ModelConfig, params, x: Array) -> Tuple[Array, Array]:
         else:
             fn = lambda xl, pl: _routed_psum(cfg, pl, xl, ctx.model_axis,
                                              mean_axes)
-        y, aux = _shard_map(
+        y, aux = jax.shard_map(
             fn, mesh=ctx.mesh, in_specs=in_specs, out_specs=out_specs,
-            **_SHARD_MAP_KW)(x, routed_params)
+            check_vma=False)(x, routed_params)
 
     y = y.astype(x.dtype)
     if "shared" in params:

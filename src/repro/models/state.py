@@ -6,7 +6,9 @@ layer-scan and shard with NamedSharding. Every state dict carries only
 arrays; the scalar clock ``t`` lives in the engine, passed per call.
 
 Layout conventions (R = segment repeat dim, added by the model's layer scan):
-  attention KV : k,v          (B, W, KVH, HD)    W = cache window capacity
+  attention KV : k,v          (B, KVH, W, HD)    W = cache window capacity
+                 (head-major: one head's lines are contiguous, the tile
+                 the paged decode kernel DMAs)
   MLA latent   : c_kv         (B, W, kv_lora_rank)
                  k_rope       (B, W, qk_rope_head_dim)
   mamba        : conv         (B, d_conv, d_in)
@@ -17,6 +19,7 @@ Layout conventions (R = segment repeat dim, added by the model's layer scan):
   slstm        : c,n,h        (B, d_in)
                  m            (B, d_in)
   encoder memory (enc-dec)   : enc_out (B, S_src, D) + per-layer cross K/V
+                               xk,xv (B, S_src, KVH, HD)
 """
 from __future__ import annotations
 
@@ -53,8 +56,8 @@ def init_attn_kv(cfg: ModelConfig, batch: int, capacity: int, dtype):
             "k_rope": jnp.zeros((batch, capacity, m.qk_rope_head_dim), dtype),
         }
     return {
-        "k": jnp.zeros((batch, capacity, cfg.num_kv_heads, cfg.head_dim), dtype),
-        "v": jnp.zeros((batch, capacity, cfg.num_kv_heads, cfg.head_dim), dtype),
+        "k": jnp.zeros((batch, cfg.num_kv_heads, capacity, cfg.head_dim), dtype),
+        "v": jnp.zeros((batch, cfg.num_kv_heads, capacity, cfg.head_dim), dtype),
     }
 
 

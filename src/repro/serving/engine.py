@@ -27,6 +27,7 @@ so prefill and decode are never co-scheduled on one instance).
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 from typing import (TYPE_CHECKING, Dict, Iterable, List, Mapping, Optional,
@@ -40,6 +41,7 @@ from repro.configs.base import ModelConfig
 from repro.kvstore import PagedStore
 from repro.models import (decode_multi, decode_step, init_state, prefill,
                           prefill_batched, prefill_chunk)
+from repro.models.attention import prefill_kernel
 from repro.models.state import state_bytes
 from repro.serving.request import Phase, Request
 from repro.serving.sampling import decode_keys, sample_slots
@@ -90,6 +92,9 @@ class InstanceEngine:
         #: device->host materializations on the decode path (the sync the
         #: fused scan amortizes: 1/token dense-per-step vs 1/plan fused)
         self.host_syncs = 0
+        #: prefills by the attention backend they ran ("pallas" flash
+        #: kernel vs "jnp" scan; the choice is a shape rule)
+        self.prefill_paths: Dict[str, int] = collections.Counter()
         #: uploaded decode block tables, keyed by (resident rids, block
         #: bucket) — slot-affine tables are growth-stable, so they only
         #: rebuild when batch membership or the bucket changes
@@ -381,6 +386,9 @@ class InstanceEngine:
             batch.update(extra)
         window = (bucket_len(req.prompt_len, cap=self.kv_capacity)
                   if self._attn_only and not extra else self.kv_capacity)
+        prefix = (extra or {}).get("patch_embeds")
+        self.prefill_paths[prefill_kernel(
+            req.prompt_len + (0 if prefix is None else prefix.shape[1]))] += 1
         fresh = init_state(self.cfg, 1, window)
         with self._mesh_ctx():
             logits, fresh = self._jit_prefill(self.params, batch, fresh)
@@ -409,6 +417,7 @@ class InstanceEngine:
             toks[i, :it.prompt_len] = np.asarray(it.req.prompt_tokens)[0]
             lens[i] = it.prompt_len
         fresh = init_state(self.cfg, Bp, bucket)
+        self.prefill_paths[prefill_kernel(bucket)] += B
         with self._mesh_ctx():
             logits, fresh = self._jit_prefill_batched(
                 self.params, jnp.asarray(toks), fresh, jnp.asarray(lens))
@@ -467,6 +476,7 @@ class InstanceEngine:
             slot = self.store.rid_slot[req.rid]
             assert self.prefilling.get(slot) is req
         toks = req.prompt_tokens[:, it.start:it.end]
+        self.prefill_paths[prefill_kernel(it.end - it.start, it.start)] += 1
         sub = self.store.extract_slot(slot)
         with self._mesh_ctx():
             logits, sub = self._jit_prefill_chunk(self.params, toks, sub,

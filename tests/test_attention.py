@@ -43,15 +43,26 @@ def test_flash_vs_naive(chunks, window, rng_key):
     assert float(jnp.abs(out - exp.astype(out.dtype)).max()) < 1e-5
 
 
-def test_ring_write_scalar_and_vector():
+@pytest.mark.parametrize("axis", [1, 2])
+def test_ring_write_scalar_and_vector(axis):
+    """Lines on axis 1 (MLA latents) or axis 2 (head-major K/V); the
+    checks read through a view with the line axis moved to 1."""
     cap = 8
-    cache = jnp.zeros((2, cap, 3))
-    vals = jnp.ones((2, 2, 3))
+
+    def lay(x):
+        return jnp.moveaxis(x, 1, axis)
+
+    def view(x):
+        return jnp.moveaxis(x, axis, 1)
+
+    cache = lay(jnp.zeros((2, cap, 3)))
+    vals = lay(jnp.ones((2, 2, 3)))
     # scalar clock, wraps
-    c1 = ring_write(cache, vals, jnp.int32(7), cap)
+    c1 = view(ring_write(cache, vals, jnp.int32(7), cap, axis=axis))
     assert float(c1[0, 7, 0]) == 1.0 and float(c1[0, 0, 0]) == 1.0
     # per-batch clock
-    c2 = ring_write(cache, vals[:, :1], jnp.array([1, 5]), cap)
+    one = lay(jnp.ones((2, 1, 3)))
+    c2 = view(ring_write(cache, one, jnp.array([1, 5]), cap, axis=axis))
     assert float(c2[0, 1, 0]) == 1.0 and float(c2[1, 5, 0]) == 1.0
     assert float(c2[0, 5, 0]) == 0.0
 
@@ -80,11 +91,12 @@ def test_decode_attention_batched_valid(rng_key):
     B, H, KVH, hd, W = 2, 4, 2, 16, 32
     k1, k2, k3 = jax.random.split(rng_key, 3)
     q = jax.random.normal(k1, (B, 1, H, hd))
-    kc = jax.random.normal(k2, (B, W, KVH, hd))
-    vc = jax.random.normal(k3, (B, W, KVH, hd))
+    kc = jax.random.normal(k2, (B, KVH, W, hd))      # head-major caches
+    vc = jax.random.normal(k3, (B, KVH, W, hd))
     valid = jnp.arange(W)[None] < jnp.array([[5], [W]])
     out = decode_attention(q, kc, vc, scale=hd ** -0.5, valid=valid)
     # manual check for request 0: only first 5 slots
-    exp = naive_attention(q[:1], kc[:1, :5], vc[:1, :5], causal=False,
+    exp = naive_attention(q[:1], kc[:1, :, :5].swapaxes(1, 2),
+                          vc[:1, :, :5].swapaxes(1, 2), causal=False,
                           scale=hd ** -0.5)
     assert float(jnp.abs(out[0] - exp[0, 0].astype(out.dtype)).max()) < 1e-5

@@ -65,8 +65,8 @@ def test_decode_kernel_sweep(shape, dtype, rng_key):
     B, H, KVH, hd, W = shape
     k1, k2, k3, k4 = jax.random.split(rng_key, 4)
     q = jax.random.normal(k1, (B, 1, H, hd), dtype)
-    kc = jax.random.normal(k2, (B, W, KVH, hd), dtype)
-    vc = jax.random.normal(k3, (B, W, KVH, hd), dtype)
+    kc = jax.random.normal(k2, (B, KVH, W, hd), dtype)
+    vc = jax.random.normal(k3, (B, KVH, W, hd), dtype)
     lengths = jax.random.randint(k4, (B,), 1, W + 1)
     out = decode_attention_pallas(q, kc, vc, lengths, block_k=128,
                                   interpret=True)
@@ -80,14 +80,14 @@ def test_decode_partial_lengths_masking(rng_key):
     B, H, KVH, hd, W = 1, 4, 2, 64, 256
     k1, k2, k3 = jax.random.split(rng_key, 3)
     q = jax.random.normal(k1, (B, 1, H, hd))
-    kc = jax.random.normal(k2, (B, W, KVH, hd))
-    vc = jax.random.normal(k3, (B, W, KVH, hd))
+    kc = jax.random.normal(k2, (B, KVH, W, hd))
+    vc = jax.random.normal(k3, (B, KVH, W, hd))
     L = 100
     lengths = jnp.array([L], jnp.int32)
     out1 = decode_attention_pallas(q, kc, vc, lengths, block_k=64,
                                    interpret=True)
-    kc2 = kc.at[:, L:].set(1e4)
-    vc2 = vc.at[:, L:].set(-1e4)
+    kc2 = kc.at[:, :, L:].set(1e4)
+    vc2 = vc.at[:, :, L:].set(-1e4)
     out2 = decode_attention_pallas(q, kc2, vc2, lengths, block_k=64,
                                    interpret=True)
     assert float(jnp.abs(out1 - out2).max()) < 1e-6
@@ -108,16 +108,18 @@ PAGED_SHAPES = [
 ]
 
 
-def _scatter_to_pool(cache, tables, block_lines, num_blocks):
-    """Place each request's contiguous cache rows into the pool blocks
-    its table names (inverse of the kernel's gather)."""
-    B, W = cache.shape[:2]
-    pool = jnp.zeros((num_blocks, block_lines) + cache.shape[2:],
-                     cache.dtype)
+def _scatter_to_pool(cache, tables, block_lines, num_blocks, per_page):
+    """Place each request's contiguous head-major cache rows into the
+    pool blocks its table names (inverse of the kernel's gather); block
+    ``i`` is line block ``i % per_page`` of page ``i // per_page``."""
+    B, KVH, W, hd = cache.shape
+    pool = jnp.zeros((num_blocks // per_page, KVH, per_page, block_lines,
+                      hd), cache.dtype)
     for b in range(B):
         for i, blk in enumerate(tables[b]):
-            rows = cache[b, i * block_lines:(i + 1) * block_lines]
-            pool = pool.at[int(blk)].set(rows)
+            rows = cache[b, :, i * block_lines:(i + 1) * block_lines]
+            pool = pool.at[int(blk) // per_page, :,
+                           int(blk) % per_page].set(rows)
     return pool
 
 
@@ -130,15 +132,15 @@ def test_paged_decode_kernel_matches_dense(shape, dtype, rng_key):
     nb = W // bl
     k1, k2, k3, k4, k5 = jax.random.split(rng_key, 5)
     q = jax.random.normal(k1, (B, 1, H, hd), dtype)
-    kc = jax.random.normal(k2, (B, W, KVH, hd), dtype)
-    vc = jax.random.normal(k3, (B, W, KVH, hd), dtype)
+    kc = jax.random.normal(k2, (B, KVH, W, hd), dtype)
+    vc = jax.random.normal(k3, (B, KVH, W, hd), dtype)
     lengths = jax.random.randint(k4, (B,), 1, W + 1)
     # a non-trivial physical placement: shuffled pool twice as large
     num_blocks = 2 * B * nb
     tables = jax.random.permutation(k5, num_blocks)[: B * nb]
     tables = tables.reshape(B, nb).astype(jnp.int32)
-    k_pool = _scatter_to_pool(kc, tables, bl, num_blocks)
-    v_pool = _scatter_to_pool(vc, tables, bl, num_blocks)
+    k_pool = _scatter_to_pool(kc, tables, bl, num_blocks, nb)
+    v_pool = _scatter_to_pool(vc, tables, bl, num_blocks, nb)
     out = paged_decode_attention_pallas(q, k_pool, v_pool, tables, lengths,
                                         interpret=True)
     exp = decode_attention_pallas(q, kc, vc, lengths, block_k=bl,
@@ -164,10 +166,10 @@ def test_paged_kernel_reads_store_block_tables(rng_key):
     lengths = [20, 37]
     for rid, slot, n in zip(rids, slots, lengths):
         store.alloc(rid, slot, lines=n)
-    # one attention leaf, repeat index 0: (B, W, KVH, hd)
+    # one attention leaf, repeat index 0: (B, KVH, W, hd)
     i, pj, key, kind = next(p for p in store._paths if p[3] == "line")
     leaf = store.state["layers"][i][pj][key][0]
-    B, W, KVH, hd = leaf.shape
+    B, KVH, W, hd = leaf.shape
     k1, k2, k3 = jax.random.split(rng_key, 3)
     kc = jax.random.normal(k1, leaf.shape)
     vc = jax.random.normal(k2, leaf.shape)

@@ -89,8 +89,9 @@ def test_long_context_kv_data_sharded_for_hybrid():
     jax.tree_util.tree_map_with_path(
         lambda p, s: flat.append((jax.tree_util.keystr(p), s)), specs,
         is_leaf=lambda x: isinstance(x, P))
+    # head-major k (R, B, KVH, W, hd): the sequence is dim 3
     ks = [s for p, s in flat if p.endswith("['k']")]
-    assert ks and all(s[2] in ("data", ("data",)) for s in ks), ks
+    assert ks and all(s[3] in ("data", ("data",)) for s in ks), ks
 
 
 def test_pick_layout_default_tp():

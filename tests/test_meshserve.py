@@ -184,6 +184,37 @@ def test_mesh_cluster_byte_accounting_matches_host_copy(mesh8, setup):
             f"{key}: mesh pod {mesh[key]} != host-copy pod {host[key]}")
 
 
+@pytest.mark.parametrize("message,falls_back", [
+    ("INVALID_ARGUMENT: Disallowed device-to-host transfer: x", True),
+    ("RESOURCE_EXHAUSTED: out of memory on the destination", False),
+])
+def test_device_transfer_falls_back_only_on_the_guard(mesh8, monkeypatch,
+                                                      message, falls_back):
+    """Only the transfer guard's refusal takes the (counted) host copy;
+    any other transfer failure propagates instead of hiding the device."""
+    from repro.meshserve import collectives
+    x = jax.device_put(jax.numpy.arange(8.0), mesh8[0])
+    like = jax.device_put(jax.numpy.zeros(8), mesh8[1])
+    real_put = jax.device_put
+
+    def put(arr, target):
+        if isinstance(arr, jax.Array):
+            raise jax.errors.JaxRuntimeError(message)
+        return real_put(arr, target)
+
+    monkeypatch.setattr(collectives.jax, "device_put", put)
+    STATS.reset()
+    if not falls_back:
+        with pytest.raises(jax.errors.JaxRuntimeError, match="out of memory"):
+            collectives.device_transfer(x, like)
+        assert STATS.host_copies == 0
+        return
+    out = collectives.device_transfer(x, like)
+    assert STATS.host_copies == 1 and STATS.d2d_copies == 0
+    assert out.devices() == {mesh8[1]}
+    assert out.tolist() == list(range(8))
+
+
 # ---------------------------------------------------------------------------
 # golden trace: heterogeneous mesh pod, live vs sim
 # ---------------------------------------------------------------------------
