@@ -42,13 +42,7 @@ from repro.configs.base import ModelConfig
 from repro.kvstore.base import BlockLedger, KVStoreError, LineCosts
 from repro.models import init_state
 from repro.models.blocks import layer_specs, plan_segments
-
-#: attention-state keys indexed by KV line, mapped to the line axis of
-#: the stacked (repeat-dim-first) leaf: head-major K/V ``(R, B, KVH, W,
-#: hd)``, MLA latents ``(R, B, W, d)``
-LINE_AXIS = {"k": 3, "v": 3, "c_kv": 2, "k_rope": 2}
-#: attention-state keys written once at prefill (enc-dec cross caches)
-STATIC_KEYS = ("xk", "xv")
+from repro.models.state import LINE_AXIS, line_index, pool_view
 
 
 def pick_block_lines(kv_capacity: int, requested: int = 16) -> int:
@@ -57,12 +51,6 @@ def pick_block_lines(kv_capacity: int, requested: int = 16) -> int:
     while kv_capacity % b:
         b -= 1
     return b
-
-
-def line_index(slot, pos, axis: int) -> tuple:
-    """Index of lines ``pos`` (array or slice) of batch row ``slot`` in a
-    stacked leaf whose line axis is ``axis``."""
-    return (slice(None), slot) + (slice(None),) * (axis - 2) + (pos,)
 
 
 # jitted copy primitives for the mirror hot path: slot indices and row
@@ -195,9 +183,7 @@ class PagedStore:
         hd)`` into the block pool ``(B, KVH, W/block_lines, block_lines,
         hd)`` addressed by :meth:`line_block_table` (a reshape, no
         copy)."""
-        B, KVH, W, hd = arr.shape
-        return arr.reshape(B, KVH, W // self.block_lines, self.block_lines,
-                           hd)
+        return pool_view(arr, self.block_lines)
 
     # -- ledger ops (slot-affine) ----------------------------------------------
     def alloc(self, rid: int, slot: int, lines: int,

@@ -8,23 +8,31 @@ from __future__ import annotations
 
 import os
 from pathlib import Path
+from typing import Optional
 
 import jax
 
-#: the fixed in-checkout directory used when the environment names none
-#: (``src/repro/launch/cache.py`` -> ``<checkout>/.jax_cache``)
-CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+#: the cache directory inside a checkout, when the environment names none
+CACHE_DIRNAME = ".jax_cache"
 
 
-def use_compile_cache() -> str:
+def use_compile_cache(checkout: Path) -> Optional[str]:
     """Turn on the persistent compilation cache; returns its directory.
 
     When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
     nothing is set here.  Otherwise the cache sits at one fixed path in
-    the checkout: the directory is part of what lets a later run find
-    its entries, so it never varies from run to run."""
+    the caller's ``checkout`` (the directory is part of what lets a later
+    run find its entries, so it never varies from run to run).  A
+    ``checkout`` that is not one — an installed package — leaves the
+    cache off (returns None) rather than write beside site-packages."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
-    return str(CHECKOUT_CACHE_DIR)
+    # a checkout has its pyproject.toml beside src/repro; an installed
+    # package has neither
+    if not ((checkout / "pyproject.toml").is_file()
+            and (checkout / "src" / "repro").is_dir()):
+        return None
+    path = str(checkout / CACHE_DIRNAME)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -28,6 +28,7 @@ decision path, so they are interchangeable with the originals.
 from __future__ import annotations
 
 import argparse
+from pathlib import Path
 
 from repro.api import ServeSpec, serve
 from repro.configs import list_archs
@@ -171,7 +172,8 @@ def main():
     ap.add_argument("--full-config", action="store_true",
                     help="use the full (non-reduced) architecture")
     args = ap.parse_args()
-    use_compile_cache()
+    # the checkout this module runs from (src/repro/launch/serve.py)
+    use_compile_cache(Path(__file__).resolve().parents[3])
 
     reuse = (PrefixReuse(pool=args.prefix_pool, reuse=args.prefix_reuse,
                          prefix_len=args.prefix_len)

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import InputShape, ModelConfig
+from repro.models.state import HEAD_AXIS, SEQ_AXIS
 
 # ---------------------------------------------------------------------------
 # Input ShapeDtypeStructs
@@ -246,26 +247,22 @@ def state_pspecs(cfg: ModelConfig, state_shape, shape: InputShape,
         bdim = 0 if is_enc else 1
         if batch_ok and nd > bdim and _div(shape_[bdim], b_n):
             spec[bdim] = b_ax
-        # stacked layouts: head-major k/v (R, B, KVH, W, hd); cross
-        # caches (R, B, S, KVH, hd); MLA latents (R, B, W, d)
-        seq_dims = {"k": 3, "v": 3, "c_kv": 2, "k_rope": 2, "xk": 2, "xv": 2}
-        head_dims = {"k": 2, "v": 2, "xk": 3, "xv": 3}
         tail = ps.rsplit("/", 1)[-1]
-        sd = seq_dims.get(tail)
+        sd = SEQ_AXIS.get(tail)
         if not batch_ok and sd is not None and nd > sd \
                 and _div(shape_[sd], b_n):
             spec[sd] = b_ax                     # shard KV seq over data
-        if tail in ("c_kv", "k_rope") and nd > 2 and spec[2] is None \
-                and _div(shape_[2], model_n):
+        if tail in ("c_kv", "k_rope") and nd > sd and spec[sd] is None \
+                and _div(shape_[sd], model_n):
             # MLA latent cache: shard the SEQUENCE dim over the model axis
             # (flash-decode-style sharded-KV; §Perf iteration 5). All heads
             # share the latent, so head-sharding the cache is impossible —
             # sequence sharding keeps HBM reads 1/16 per chip and replaces
             # two per-layer latent all-gathers with tiny softmax-combine
             # all-reduces.
-            spec[2] = "model"
+            spec[sd] = "model"
         # model-parallel inner dims where divisible
-        hd_ = head_dims.get(tail)
+        hd_ = HEAD_AXIS.get(tail)
         if hd_ is not None and nd >= 4 and _div(shape_[hd_], model_n):
             spec[hd_] = "model"                 # kv heads
         if tail == "ssm" and nd >= 3 and _div(shape_[2], model_n):

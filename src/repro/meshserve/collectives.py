@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.kvstore.paged import line_index
+from repro.models.state import line_index
 
 
 @dataclass

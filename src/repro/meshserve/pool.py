@@ -21,10 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch.specs import param_pspecs
 from repro.meshserve.topology import MeshSlice
-
-#: store leaves sharded on their KV head dim, mapped to that dim: the
-#: head-major k/v line caches and the enc-dec cross caches
-_HEAD_DIM = {"k": 2, "v": 2, "xk": 3, "xv": 3}
+from repro.models.state import HEAD_AXIS
 
 
 def shard_params(cfg, params, sl: MeshSlice):
@@ -42,7 +39,7 @@ def shard_store(store, sl: MeshSlice) -> None:
     for i, pj, key, kind in store._paths:
         arr = store.state["layers"][i][pj][key]
         spec = [None] * arr.ndim
-        dim = _HEAD_DIM.get(key)
+        dim = HEAD_AXIS.get(key)
         if dim is not None and arr.shape[dim] % sl.tp == 0:
             spec[dim] = "model"
         store.state["layers"][i][pj][key] = jax.device_put(

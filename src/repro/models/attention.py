@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.common import KeyGen, apply_rope, dense_init, rms_norm
+from repro.models.state import KV_HEAD_AXIS, KV_LINE_AXIS, pool_view
 
 Array = jax.Array
 
@@ -249,10 +250,11 @@ def ring_write(cache: Array, values: Array, t: Array, capacity: int,
 def _write_kv(state: dict, k: Array, v: Array, t: Array) -> dict:
     """Ring-write the (B, S, KVH, hd) K/V projections at [t, t+S) into
     the head-major (B, KVH, W, hd) cache."""
-    cap = state["k"].shape[2]
+    cap = state["k"].shape[KV_LINE_AXIS]
+    k, v = (jnp.moveaxis(x, 2, KV_HEAD_AXIS) for x in (k, v))
     return dict(state,
-                k=ring_write(state["k"], k.swapaxes(1, 2), t, cap, axis=2),
-                v=ring_write(state["v"], v.swapaxes(1, 2), t, cap, axis=2))
+                k=ring_write(state["k"], k, t, cap, axis=KV_LINE_AXIS),
+                v=ring_write(state["v"], v, t, cap, axis=KV_LINE_AXIS))
 
 
 def ring_valid(t_next: Array, capacity: int) -> Array:
@@ -312,12 +314,13 @@ def gqa_forward(
         # the previously cached rows (already roped at their absolute
         # positions) plus the chunk itself; new rows land at [t, t+S).
         assert state is not None and causal
-        k_all = jnp.concatenate(
-            [state["k"][:, :, :history].swapaxes(1, 2).astype(k.dtype), k],
-            axis=1)
-        v_all = jnp.concatenate(
-            [state["v"][:, :, :history].swapaxes(1, 2).astype(v.dtype), v],
-            axis=1)
+        def cached(c):
+            # (B, KVH, W, hd) rows [0, history) -> (B, history, KVH, hd)
+            rows = jax.lax.slice_in_dim(c, 0, history, axis=KV_LINE_AXIS)
+            return jnp.moveaxis(rows, KV_HEAD_AXIS, 2).astype(k.dtype)
+
+        k_all = jnp.concatenate([cached(state["k"]), k], axis=1)
+        v_all = jnp.concatenate([cached(state["v"]), v], axis=1)
         out = flash_attention(q, k_all, v_all, causal=True, scale=scale,
                               window=window, q_offset=history)
         new_state = state
@@ -351,21 +354,20 @@ def gqa_forward(
         # head dim to the slice's model axis so the per-head block gather
         # below stays shard-local (no-op without an active mesh)
         q = sharding.constrain(q, "batch", None, "model", None)
-        cap = state["k"].shape[2]
+        cap = state["k"].shape[KV_LINE_AXIS]
         pos = t % cap
         kc = state["k"].at[paged.slots, :, pos].set(k[:, 0])
         vc = state["v"].at[paged.slots, :, pos].set(v[:, 0])
         bl = paged.block_lines
-        pool_shape = (kc.shape[0], kvh, cap // bl, bl, hd)
         lengths = jnp.minimum(t + 1, cap)
         out = paged_decode_attention(
-            q, kc.reshape(pool_shape), vc.reshape(pool_shape),
+            q, pool_view(kc, bl), pool_view(vc, bl),
             paged.tables, lengths, scale=scale, backend=kernel_backend())
         new_state = dict(state, k=kc, v=vc)
     elif mode == "decode":
         assert state is not None and t is not None
         new_state = _write_kv(state, k, v, t)
-        valid = ring_valid(t + S, state["k"].shape[2])
+        valid = ring_valid(t + S, state["k"].shape[KV_LINE_AXIS])
         out = decode_attention(q, new_state["k"], new_state["v"],
                                scale=scale, valid=valid)
     else:

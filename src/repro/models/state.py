@@ -29,6 +29,36 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 
+#: head and line axes of one layer's head-major K/V leaf (B, KVH, W, HD)
+KV_HEAD_AXIS, KV_LINE_AXIS = 1, 2
+
+# Axes of the *stacked* attention-state leaves (the layer scan prepends R),
+# by state key.  Every consumer of the layout (the paged store's row
+# copies, the mesh pool's head sharding, the launch-layer partition specs)
+# reads these tables.
+#: KV line (cache window) axis of the per-token caches
+LINE_AXIS = {"k": KV_LINE_AXIS + 1, "v": KV_LINE_AXIS + 1,
+             "c_kv": 2, "k_rope": 2}
+#: sequence axis: the per-token caches' lines, the cross caches' source
+SEQ_AXIS = dict(LINE_AXIS, xk=2, xv=2)
+#: KV-head axis of the GQA caches
+HEAD_AXIS = {"k": KV_HEAD_AXIS + 1, "v": KV_HEAD_AXIS + 1, "xk": 3, "xv": 3}
+
+
+def pool_view(cache, block_lines: int):
+    """One layer's head-major K/V leaf ``(B, KVH, W, HD)`` as the paged
+    block pool ``(B, KVH, W/block_lines, block_lines, HD)`` the decode
+    kernel gathers from (a reshape, no copy): block id ``b * W/block_lines
+    + j`` is line block ``j`` of batch row ``b``."""
+    B, KVH, W, HD = cache.shape
+    return cache.reshape(B, KVH, W // block_lines, block_lines, HD)
+
+
+def line_index(slot, pos, axis: int) -> tuple:
+    """Index of lines ``pos`` (array or slice) of batch row ``slot`` in a
+    stacked leaf whose line axis is ``axis``."""
+    return (slice(None), slot) + (slice(None),) * (axis - 2) + (pos,)
+
 
 def cache_capacity(cfg: ModelConfig, seq_len: int, long_context: bool) -> int:
     """KV capacity for attention layers: ring-buffer window when the

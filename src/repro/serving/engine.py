@@ -41,7 +41,6 @@ from repro.configs.base import ModelConfig
 from repro.kvstore import PagedStore
 from repro.models import (decode_multi, decode_step, init_state, prefill,
                           prefill_batched, prefill_chunk)
-from repro.models.attention import prefill_kernel
 from repro.models.state import state_bytes
 from repro.serving.request import Phase, Request
 from repro.serving.sampling import decode_keys, sample_slots
@@ -92,9 +91,13 @@ class InstanceEngine:
         #: device->host materializations on the decode path (the sync the
         #: fused scan amortizes: 1/token dense-per-step vs 1/plan fused)
         self.host_syncs = 0
-        #: prefills by the attention backend they ran ("pallas" flash
-        #: kernel vs "jnp" scan; the choice is a shape rule)
-        self.prefill_paths: Dict[str, int] = collections.Counter()
+        #: model programs dispatched, by jit and argument shape key —
+        #: ("prefill", prompt_len, window), ("prefill_batched", rows,
+        #: bucket), ("prefill_chunk", chunk_len, history), ("decode",),
+        #: ("decode_multi", rows, blocks, steps) — counting the requests
+        #: each carried: the record a check re-lowers the served programs
+        #: from
+        self.programs: Dict[tuple, int] = collections.Counter()
         #: uploaded decode block tables, keyed by (resident rids, block
         #: bucket) — slot-affine tables are growth-stable, so they only
         #: rebuild when batch membership or the bucket changes
@@ -386,9 +389,7 @@ class InstanceEngine:
             batch.update(extra)
         window = (bucket_len(req.prompt_len, cap=self.kv_capacity)
                   if self._attn_only and not extra else self.kv_capacity)
-        prefix = (extra or {}).get("patch_embeds")
-        self.prefill_paths[prefill_kernel(
-            req.prompt_len + (0 if prefix is None else prefix.shape[1]))] += 1
+        self.programs["prefill", req.prompt_len, window] += 1
         fresh = init_state(self.cfg, 1, window)
         with self._mesh_ctx():
             logits, fresh = self._jit_prefill(self.params, batch, fresh)
@@ -417,7 +418,7 @@ class InstanceEngine:
             toks[i, :it.prompt_len] = np.asarray(it.req.prompt_tokens)[0]
             lens[i] = it.prompt_len
         fresh = init_state(self.cfg, Bp, bucket)
-        self.prefill_paths[prefill_kernel(bucket)] += B
+        self.programs["prefill_batched", Bp, bucket] += B
         with self._mesh_ctx():
             logits, fresh = self._jit_prefill_batched(
                 self.params, jnp.asarray(toks), fresh, jnp.asarray(lens))
@@ -476,7 +477,7 @@ class InstanceEngine:
             slot = self.store.rid_slot[req.rid]
             assert self.prefilling.get(slot) is req
         toks = req.prompt_tokens[:, it.start:it.end]
-        self.prefill_paths[prefill_kernel(it.end - it.start, it.start)] += 1
+        self.programs["prefill_chunk", it.end - it.start, it.start] += 1
         sub = self.store.extract_slot(slot)
         with self._mesh_ctx():
             logits, sub = self._jit_prefill_chunk(self.params, toks, sub,
@@ -510,6 +511,7 @@ class InstanceEngine:
                     for slot, toks in self.decode_multi(steps=1).items()}
         tokens = jnp.asarray(self.last_tokens)[:, None]
         t = jnp.asarray(self.lengths)
+        self.programs["decode",] += len(self.slot_req)
         with self._mesh_ctx():
             logits, self.store.state = self._jit_decode(
                 self.params, tokens, self.store.state, t)
@@ -587,6 +589,7 @@ class InstanceEngine:
                                                blocks)))
         tables = self._tables_cache[1]
         key_chain, keys = decode_keys(self._key, steps)
+        self.programs["decode_multi", len(slots), blocks, steps] += len(slots)
         with self._mesh_ctx():
             toks_all, self.store.state, emitted = self._jit_decode_multi(
                 self.params, jnp.asarray(self.last_tokens[slots])[:, None],
