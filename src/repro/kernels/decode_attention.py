@@ -137,6 +137,7 @@ def paged_decode_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode",
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
       qg, k_pool, v_pool)
 

@@ -140,5 +140,6 @@ def flash_attention_pallas(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_prefill",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

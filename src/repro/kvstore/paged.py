@@ -38,6 +38,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.kvstore.base import BlockLedger, KVStoreError, LineCosts
 from repro.models import init_state
@@ -306,31 +307,33 @@ class PagedStore:
         may be smaller than the store's) and for resumed chunk writes.
         Recurrent and static leaves copy whole; row bounds clamp to
         whichever window is smaller."""
-        for i, pj, key, kind in self._paths:
-            dst = self.state["layers"][i][pj][key]
-            src = sub_state["layers"][i][pj][key]
-            if not _colocated(dst, src):
-                from repro.meshserve import collectives
-                src = collectives.device_transfer(src, dst)
-            if kind == "line":
-                ax = LINE_AXIS[key]
-                h = min(hi, src.shape[ax], dst.shape[ax])
-                l = min(lo, h)
-                if h <= l:
-                    continue
-                self.state["layers"][i][pj][key] = dst.at[
-                    line_index(slot, slice(l, h), ax)].set(
-                        src[line_index(src_slot, slice(l, h), ax)])
-            else:
-                self.state["layers"][i][pj][key] = dst.at[:, slot].set(
-                    src[:, src_slot])
-        if "enc_out" in self.state:
-            enc = sub_state["enc_out"]
-            if not _colocated(self.state["enc_out"], enc):
-                from repro.meshserve import collectives
-                enc = collectives.device_transfer(enc, self.state["enc_out"])
-            self.state["enc_out"] = self.state["enc_out"].at[slot].set(
-                enc[src_slot])
+        with obs.span("kv.merge"):
+            for i, pj, key, kind in self._paths:
+                dst = self.state["layers"][i][pj][key]
+                src = sub_state["layers"][i][pj][key]
+                if not _colocated(dst, src):
+                    from repro.meshserve import collectives
+                    src = collectives.device_transfer(src, dst)
+                if kind == "line":
+                    ax = LINE_AXIS[key]
+                    h = min(hi, src.shape[ax], dst.shape[ax])
+                    l = min(lo, h)
+                    if h <= l:
+                        continue
+                    self.state["layers"][i][pj][key] = dst.at[
+                        line_index(slot, slice(l, h), ax)].set(
+                            src[line_index(src_slot, slice(l, h), ax)])
+                else:
+                    self.state["layers"][i][pj][key] = dst.at[:, slot].set(
+                        src[:, src_slot])
+            if "enc_out" in self.state:
+                enc = sub_state["enc_out"]
+                if not _colocated(self.state["enc_out"], enc):
+                    from repro.meshserve import collectives
+                    enc = collectives.device_transfer(
+                        enc, self.state["enc_out"])
+                self.state["enc_out"] = self.state["enc_out"].at[slot].set(
+                    enc[src_slot])
 
     # -- per-layer streamed transfer (§4.2.4) ----------------------------------
     def stream_slot(self, slot: int) -> Iterator[Tuple[tuple, jnp.ndarray]]:

@@ -13,11 +13,14 @@ instance per iteration); latency metrics are reported in iterations.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass
 from typing import TYPE_CHECKING, Dict, List, Optional, Tuple, Union
 
+from repro import obs
 from repro.configs.base import ModelConfig
+from repro.meshserve import STATS as TRANSFERS
 from repro.scheduling.actions import (AbortRequest, Action, Decode,
                                       EvictReplica, MirrorSync, Prefill,
                                       PromoteReplica, StreamState)
@@ -318,11 +321,11 @@ class LiveCluster:
         #: replay keeps O(n/stride) observability memory
         self.timeline_stride = max(1, timeline_stride)
         #: wall-clock seconds spent in scheduling decisions (policy +
-        #: planner), excluding engine execution — the live counterpart
-        #: of ``Simulator.sched_time_s``
+        #: planner), excluding engine execution — the sum of the
+        #: ``sched.*`` spans this cluster opened; the live counterpart of
+        #: ``Simulator.sched_time_s``
         self.sched_time_s = 0.0
         self.n_iterations = 0
-        self._sched_t0: Optional[float] = None
         self.stats = {"prefills": 0, "decode_steps": 0, "rebalances": 0,
                       "replica_promotions": 0, "replica_evictions": 0,
                       "mirror_syncs": 0, "mirror_bytes": 0.0,
@@ -341,15 +344,16 @@ class LiveCluster:
         """Mean scheduler overhead per iteration, microseconds."""
         return self.sched_time_s * 1e6 / max(1, self.n_iterations)
 
-    def _sched_begin(self):
-        import time
-        self._sched_t0 = time.perf_counter()
-
-    def _sched_end(self):
-        import time
-        if self._sched_t0 is not None:
-            self.sched_time_s += time.perf_counter() - self._sched_t0
-            self._sched_t0 = None
+    @contextlib.contextmanager
+    def _sched(self, name: str):
+        """One scheduling decision: a ``sched.*`` span whose length adds
+        to ``sched_time_s``."""
+        sp = obs.span(name)
+        try:
+            with sp:
+                yield
+        finally:
+            self.sched_time_s += sp.dur_ns / 1e9
 
     # -- submission -----------------------------------------------------------
     def submit(self, req: Request, extra: Optional[dict] = None, *,
@@ -357,6 +361,9 @@ class LiveCluster:
         """Enqueue a request.  Open-loop sources pass
         ``stamp_arrival=False`` to preserve the traffic layer's arrival
         time (which may fall between scheduling iterations)."""
+        req.submit_ns = obs.clock_ns()
+        obs.record("request.submit", req.submit_ns, req.submit_ns,
+                   rid=req.rid)
         if stamp_arrival:
             req.arrival = self.now
         if extra is None:
@@ -524,6 +531,10 @@ class LiveCluster:
 
     # -- one scheduling iteration ---------------------------------------------
     def step(self):
+        with obs.span("serve.step"):
+            self._step()
+
+    def _step(self):
         self.clock.tick()
         # fleet events apply between scheduler iterations: the view the
         # policy reads below already reflects kills/joins/drains
@@ -546,11 +557,143 @@ class LiveCluster:
         view = LiveClusterView(self)
 
         # scheduling decisions (routing, roles, admission, plan compile)
-        # are timed; engine execution below is not — the same split the
-        # simulator's sched_us_per_iter uses
-        self._sched_begin()
-
+        # are timed as sched.* spans; engine execution below is not —
+        # the same split the simulator's sched_us_per_iter uses
         # 1. routing: policy assigns queued requests to instances
+        with self._sched("sched.route"):
+            self._route(view)
+        # 2. roles -> declarative step actions; the planner compiles them
+        # into per-instance plans (bucketing, chunk cursors, and the
+        # §4.2.3 no-mixing invariant all live there, not here)
+        with self._sched("sched.plan"):
+            plans, taken_now = self._plan(view)
+
+        # chunk budget may not have reached every admitted request this
+        # iteration: return the unplanned ones to the head of the backlog
+        planned_rids = set()
+        for plan in plans:
+            pf = prefill_part(plan)
+            if pf is not None:
+                planned_rids.update(it.rid for it in pf.items)
+        for idx, taken in taken_now.items():
+            unplanned = [(r, e) for r, e in taken
+                         if r.rid not in planned_rids]
+            if unplanned:
+                self._pending[idx][:0] = unplanned
+
+        # 3. execute the plans in the executor's phase order: all
+        # prefills, then post-prefill placement, then all decodes — so a
+        # request streamed to its decode primary still joins that
+        # instance's decode batch within the same iteration
+        prefilled = set()
+        decoded = set()
+        newly: List[Tuple[int, Request]] = []
+        for plan in plans:
+            pf = prefill_part(plan)
+            if pf is not None:
+                self._execute_prefill(pf, newly, prefilled)
+
+        # 4. post-prefill placement (§4.1.2 streaming / Splitwise
+        # transfer), wrapped into transfer plans
+        for idx, req in newly:
+            with self._sched("sched.place"):
+                acts = self.policy.place_after_prefill(view, idx, req)
+            self._apply_transfers(acts, view)
+
+        ran_steps = 1
+        for plan in plans:
+            dc = decode_part(plan)
+            if dc is None or not self.engines[dc.instance].slot_req:
+                continue
+            eng = self.engines[dc.instance]
+            # graceful-degradation ladder (§4.2.5) before the step can
+            # allocate: evict replicas, then abort least-progress work
+            self._relieve_pressure(dc.instance, view)
+            if not eng.slot_req:
+                continue
+            if self.degrade_dispatch_s > 0.0 \
+                    and self.degrade[dc.instance] > 1.0:
+                # calibrated physical injection: a degraded instance's
+                # dispatch really takes (factor-1) x the knob longer
+                import time
+                time.sleep((self.degrade[dc.instance] - 1.0)
+                           * self.degrade_dispatch_s)
+            live = {s: eng.slot_req[s] for s in eng.active_slots()}
+            out = eng.decode_multi(dc)
+            if out:
+                # account the span actually executed: EOS can end a
+                # fused block before dc.steps (the budget cap cannot
+                # foresee a sampled eos_token)
+                ran = max(len(toks) for toks in out.values())
+                self.stats["decode_steps"] += ran
+                ran_steps = max(ran_steps, ran)
+                decoded.add(dc.instance)
+            for slot, toks in out.items():
+                req = live[slot]
+                for j in range(len(toks)):
+                    req.token_times.append(self.now + j)
+                if (dc.steps > 1 and req.phase is Phase.DONE
+                        and req.finish_time is None):
+                    # died mid-span (EOS): stamp the iteration it really
+                    # finished, not the end of the fused block
+                    req.finish_time = self.now + len(toks) - 1
+                    self.finished.append(req)
+        if ran_steps > 1:
+            # a fused block IS ran_steps scheduling iterations: advance
+            # the clock so latencies stay comparable to per-step decode
+            self.clock.tick(ran_steps - 1)
+
+        # 5. release placements of finished requests
+        self._release_finished()
+
+        # 6. mirror newly generated lines into replicas (§4.1.2)
+        with self._sched("sched.sync"):
+            sync_acts = self.policy.sync(view)
+        self._apply_transfers(sync_acts, view)
+
+        # 7. pair-level load balancing via replica promotion (§4.1.3)
+        if self.policy.requires_pairs:
+            for pair_index in range(len(self.engines) // 2):
+                with self._sched("sched.rebalance"):
+                    acts = self.policy.rebalance(view, pair_index)
+                self._apply_transfers(acts, view)
+                if acts:
+                    self.stats["rebalances"] += 1
+
+        # 8. policies that re-route every iteration reclaim their backlog
+        if self.policy.requeue_unplaced:
+            stranded = [item for pending in self._pending for item in pending]
+            if stranded:
+                # a stamped hit is instance-local: releasing the backlog
+                # for re-routing must drop the pin (it re-stamps wherever
+                # it lands next iteration)
+                for idx, pending in enumerate(self._pending):
+                    for req, _ in pending:
+                        if req.prefix_hit is not None:
+                            self.engines[idx].prefix_abandon(req)
+                    pending.clear()
+                self.queue[:0] = stranded
+
+        # 9. observability: queue depth + per-phase utilization this iteration
+        # (a fused block IS ran_steps scheduling iterations: the one
+        # scheduling pass amortizes over all of them)
+        self.n_iterations += ran_steps
+        if (self.n_iterations - 1) % self.timeline_stride >= ran_steps:
+            return
+        n = len(self.engines)
+        busy = prefilled | decoded
+        self.timeline.append(TimelinePoint(
+            t=self.now,
+            # mid-chunk prompts count as queued (the simulator keeps
+            # them in prefill_queue until the final chunk, so the two
+            # backends report comparable queue depths under chunking)
+            queue_depth=(len(self.queue) + sum(len(p) for p in self._pending)
+                         + sum(len(c) for c in self._chunking)),
+            n_prefill=len(prefilled),
+            n_decode=len(decoded - prefilled),
+            n_idle=n - len(busy)))
+
+    def _route(self, view):
         admitted = 0
         limit = self.policy.admissions_per_step(view)
         while self.queue and admitted < limit:
@@ -562,9 +705,10 @@ class LiveCluster:
             self._pending[target].append((req, extra))
             admitted += 1
 
-        # 2. roles -> declarative step actions; the planner compiles them
-        # into per-instance plans (bucketing, chunk cursors, and the
-        # §4.2.3 no-mixing invariant all live there, not here)
+    def _plan(self, view):
+        """Roles, admissions and the compiled plans of one iteration;
+        returns ``(plans, taken_now)``, the requests each instance took
+        from its backlog."""
         roles = {i: (self.policy.choose_roles(view, i) if self.alive[i]
                      else ROLE_IDLE)
                  for i in range(len(self.engines))}
@@ -621,142 +765,7 @@ class LiveCluster:
             # streamed in after prefill decodes this same iteration
             if roles[idx] != ROLE_PREFILL or not pf_actions:
                 actions.append(Decode(idx))
-        plans = self.planner.compile(actions, view)
-        self._sched_end()
-
-        # chunk budget may not have reached every admitted request this
-        # iteration: return the unplanned ones to the head of the backlog
-        planned_rids = set()
-        for plan in plans:
-            pf = prefill_part(plan)
-            if pf is not None:
-                planned_rids.update(it.rid for it in pf.items)
-        for idx, taken in taken_now.items():
-            unplanned = [(r, e) for r, e in taken
-                         if r.rid not in planned_rids]
-            if unplanned:
-                self._pending[idx][:0] = unplanned
-
-        # 3. execute the plans in the executor's phase order: all
-        # prefills, then post-prefill placement, then all decodes — so a
-        # request streamed to its decode primary still joins that
-        # instance's decode batch within the same iteration
-        prefilled = set()
-        decoded = set()
-        newly: List[Tuple[int, Request]] = []
-        for plan in plans:
-            pf = prefill_part(plan)
-            if pf is not None:
-                self._execute_prefill(pf, newly, prefilled)
-
-        # 4. post-prefill placement (§4.1.2 streaming / Splitwise
-        # transfer), wrapped into transfer plans
-        for idx, req in newly:
-            self._sched_begin()
-            try:
-                acts = self.policy.place_after_prefill(view, idx, req)
-            finally:
-                self._sched_end()
-            self._apply_transfers(acts, view)
-
-        ran_steps = 1
-        for plan in plans:
-            dc = decode_part(plan)
-            if dc is None or not self.engines[dc.instance].slot_req:
-                continue
-            eng = self.engines[dc.instance]
-            # graceful-degradation ladder (§4.2.5) before the step can
-            # allocate: evict replicas, then abort least-progress work
-            self._relieve_pressure(dc.instance, view)
-            if not eng.slot_req:
-                continue
-            if self.degrade_dispatch_s > 0.0 \
-                    and self.degrade[dc.instance] > 1.0:
-                # calibrated physical injection: a degraded instance's
-                # dispatch really takes (factor-1) x the knob longer
-                import time
-                time.sleep((self.degrade[dc.instance] - 1.0)
-                           * self.degrade_dispatch_s)
-            live = {s: eng.slot_req[s] for s in eng.active_slots()}
-            out = eng.decode_multi(dc)
-            if out:
-                # account the span actually executed: EOS can end a
-                # fused block before dc.steps (the budget cap cannot
-                # foresee a sampled eos_token)
-                ran = max(len(toks) for toks in out.values())
-                self.stats["decode_steps"] += ran
-                ran_steps = max(ran_steps, ran)
-                decoded.add(dc.instance)
-            for slot, toks in out.items():
-                req = live[slot]
-                for j in range(len(toks)):
-                    req.token_times.append(self.now + j)
-                if (dc.steps > 1 and req.phase is Phase.DONE
-                        and req.finish_time is None):
-                    # died mid-span (EOS): stamp the iteration it really
-                    # finished, not the end of the fused block
-                    req.finish_time = self.now + len(toks) - 1
-                    self.finished.append(req)
-        if ran_steps > 1:
-            # a fused block IS ran_steps scheduling iterations: advance
-            # the clock so latencies stay comparable to per-step decode
-            self.clock.tick(ran_steps - 1)
-
-        # 5. release placements of finished requests
-        self._release_finished()
-
-        # 6. mirror newly generated lines into replicas (§4.1.2)
-        self._sched_begin()
-        try:
-            sync_acts = self.policy.sync(view)
-        finally:
-            self._sched_end()
-        self._apply_transfers(sync_acts, view)
-
-        # 7. pair-level load balancing via replica promotion (§4.1.3)
-        if self.policy.requires_pairs:
-            for pair_index in range(len(self.engines) // 2):
-                self._sched_begin()
-                try:
-                    acts = self.policy.rebalance(view, pair_index)
-                finally:
-                    self._sched_end()
-                self._apply_transfers(acts, view)
-                if acts:
-                    self.stats["rebalances"] += 1
-
-        # 8. policies that re-route every iteration reclaim their backlog
-        if self.policy.requeue_unplaced:
-            stranded = [item for pending in self._pending for item in pending]
-            if stranded:
-                # a stamped hit is instance-local: releasing the backlog
-                # for re-routing must drop the pin (it re-stamps wherever
-                # it lands next iteration)
-                for idx, pending in enumerate(self._pending):
-                    for req, _ in pending:
-                        if req.prefix_hit is not None:
-                            self.engines[idx].prefix_abandon(req)
-                    pending.clear()
-                self.queue[:0] = stranded
-
-        # 9. observability: queue depth + per-phase utilization this iteration
-        # (a fused block IS ran_steps scheduling iterations: the one
-        # scheduling pass amortizes over all of them)
-        self.n_iterations += ran_steps
-        if (self.n_iterations - 1) % self.timeline_stride >= ran_steps:
-            return
-        n = len(self.engines)
-        busy = prefilled | decoded
-        self.timeline.append(TimelinePoint(
-            t=self.now,
-            # mid-chunk prompts count as queued (the simulator keeps
-            # them in prefill_queue until the final chunk, so the two
-            # backends report comparable queue depths under chunking)
-            queue_depth=(len(self.queue) + sum(len(p) for p in self._pending)
-                         + sum(len(c) for c in self._chunking)),
-            n_prefill=len(prefilled),
-            n_decode=len(decoded - prefilled),
-            n_idle=n - len(busy)))
+        return self.planner.compile(actions, view), taken_now
 
     # -- fleet mechanics (repro.fleet) ---------------------------------------
     def _fleet_ctrl(self) -> "FleetController":
@@ -1022,6 +1031,16 @@ class LiveCluster:
         else:
             raise ValueError(f"live executor cannot apply {act!r}")
 
+    @contextlib.contextmanager
+    def _kv_span(self, name: str, src: int, dst: int):
+        """A ``kv.*`` span over one state movement from instance ``src``
+        into ``dst``; ``cross`` says whether it hopped between devices."""
+        hops = TRANSFERS.d2d_copies + TRANSFERS.host_copies
+        with obs.span(name, instance=dst, src=src) as sp:
+            yield sp
+            sp.attrs["cross"] = (TRANSFERS.d2d_copies
+                                 + TRANSFERS.host_copies) != hops
+
     def _apply_stream(self, act: StreamState):
         pl = self.placements.get(act.rid)
         if pl is None or pl.primary[0] != act.src:
@@ -1036,30 +1055,35 @@ class LiveCluster:
             return                       # capacity raced away; stay put
         dst_slot = free[0]
         req = src.slot_req[src_slot]
-        # per-layer streamed transfer (§4.2.4): the state moves one
-        # layer chunk at a time — the unit a mesh overlaps with prefill
-        chunks, length, last_tok, lines = src.export_stream(src_slot)
-        if act.as_replica:
-            # primary stays at src; dst hosts a redundant copy
-            dst.import_stream(dst_slot, chunks, length, last_tok, lines,
-                              req, as_replica_of=(src.instance_id, src_slot))
-            pl.replica = (act.dst, dst_slot)
-        else:
-            dst.import_stream(dst_slot, chunks, length, last_tok, lines, req)
-            if act.retain_replica:
-                src.demote_to_replica(src_slot,
-                                      of=(dst.instance_id, dst_slot))
-                pl.replica = (src_idx, src_slot)
+        with self._kv_span("kv.stream", src_idx, act.dst) as sp:
+            # per-layer streamed transfer (§4.2.4): the state moves one
+            # layer chunk at a time — the unit a mesh overlaps with prefill
+            chunks, length, last_tok, lines = src.export_stream(src_slot)
+            if act.as_replica:
+                # primary stays at src; dst hosts a redundant copy
+                dst.import_stream(dst_slot, chunks, length, last_tok, lines,
+                                  req,
+                                  as_replica_of=(src.instance_id, src_slot))
+                pl.replica = (act.dst, dst_slot)
             else:
-                src.release(src_slot)
-            pl.primary = (act.dst, dst_slot)
-        # head lines already resident in dst's prefix cache are adopted,
-        # not moved: charge only the unique suffix (planner prices the
-        # same subtraction via StreamState.skip_lines)
-        skip = min(lines, dst.store.shared_head_lines(act.rid))
+                dst.import_stream(dst_slot, chunks, length, last_tok, lines,
+                                  req)
+                if act.retain_replica:
+                    src.demote_to_replica(src_slot,
+                                          of=(dst.instance_id, dst_slot))
+                    pl.replica = (src_idx, src_slot)
+                else:
+                    src.release(src_slot)
+                pl.primary = (act.dst, dst_slot)
+            # head lines already resident in dst's prefix cache are
+            # adopted, not moved: charge only the unique suffix (planner
+            # prices the same subtraction via StreamState.skip_lines)
+            skip = min(lines, dst.store.shared_head_lines(act.rid))
+            moved = (src.store.costs.bytes_at(lines)
+                     - skip * src.store.costs.line_bytes)
+            sp.attrs["bytes"] = moved
         self.stats["stream_skipped_lines"] += skip
-        self.stats["stream_bytes"] += (src.store.costs.bytes_at(lines)
-                                       - skip * src.store.costs.line_bytes)
+        self.stats["stream_bytes"] += moved
 
     def _apply_mirror(self, act: MirrorSync):
         pl = self.placements.get(act.rid)
@@ -1070,9 +1094,11 @@ class LiveCluster:
         src = self.engines[p_idx]
         if p_slot not in src.slot_req:
             return
-        moved = self.engines[r_idx].sync_replica_from(
-            src, p_slot, r_slot, from_line=act.from_line,
-            to_line=act.to_line)
+        with self._kv_span("kv.mirror", p_idx, r_idx) as sp:
+            moved = self.engines[r_idx].sync_replica_from(
+                src, p_slot, r_slot, from_line=act.from_line,
+                to_line=act.to_line)
+            sp.attrs["bytes"] = moved
         self.stats["mirror_syncs"] += 1
         self.stats["mirror_bytes"] += moved
 
@@ -1089,7 +1115,9 @@ class LiveCluster:
         # replica must absorb the unsynced tail before taking the
         # primary role — promotion itself moves no bytes
         if dst.store.synced_line(req.rid) < src.store.lines(req.rid):
-            moved = dst.sync_replica_from(src, p_slot, r_slot)
+            with self._kv_span("kv.mirror", p_idx, r_idx) as sp:
+                moved = dst.sync_replica_from(src, p_slot, r_slot)
+                sp.attrs["bytes"] = moved
             self.stats["mirror_syncs"] += 1
             self.stats["mirror_bytes"] += moved
         # zero-cost migration: promote replica, demote primary

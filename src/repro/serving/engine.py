@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.kvstore import PagedStore
 from repro.models import (decode_multi, decode_step, init_state, prefill,
@@ -48,6 +49,15 @@ from repro.serving.sampling import decode_keys, sample_slots
 if TYPE_CHECKING:  # runtime import is lazy: stepplan -> ... -> engine cycle
     from repro.stepplan import (DecodePlan, PrefillItem,  # noqa: F401
                                 PrefillPlan)
+
+
+def _named_partial(fn, *args, **kwargs):
+    """``functools.partial`` under ``fn``'s name, so its jit and the
+    programs in a device trace read ``jit_<name>`` (a bare partial reads
+    ``jit__unknown``)."""
+    part = functools.partial(fn, *args, **kwargs)
+    part.__name__ = fn.__name__
+    return part
 
 
 class InstanceEngine:
@@ -103,14 +113,14 @@ class InstanceEngine:
         #: rebuild when batch membership or the bucket changes
         self._tables_cache: Optional[Tuple[tuple, jnp.ndarray]] = None
         self._jit_decode = jax.jit(
-            functools.partial(decode_step, cfg), donate_argnums=(2,))
-        self._jit_prefill = jax.jit(functools.partial(prefill, cfg))
+            _named_partial(decode_step, cfg), donate_argnums=(2,))
+        self._jit_prefill = jax.jit(_named_partial(prefill, cfg))
         # bucketed batched prefill: one compile per (batch, bucket) shape
         self._jit_prefill_batched = jax.jit(
-            functools.partial(prefill_batched, cfg))
+            _named_partial(prefill_batched, cfg))
         # chunk resume: `history` is the static cursor
         self._jit_prefill_chunk = jax.jit(
-            functools.partial(prefill_chunk, cfg),
+            _named_partial(prefill_chunk, cfg),
             static_argnames=("history",))
         # the padded batched path and chunk resume need every KV row to
         # be maskable by the decode clock — attention-only decoder stacks
@@ -138,7 +148,7 @@ class InstanceEngine:
         # fused multi-step decode: compiles per (batch, table, steps)
         # shape; eos/temperature are baked in as compile-time constants
         self._jit_decode_multi = jax.jit(
-            functools.partial(
+            _named_partial(
                 decode_multi, cfg, block_lines=self.store.block_lines,
                 temperature=temperature,
                 eos_token=-1 if eos_token is None else eos_token),
@@ -377,6 +387,23 @@ class InstanceEngine:
         if self.prefix_cache is not None:
             self._prefix_insert(req)
 
+    def _read_first_tokens(self, sampled, reqs: List[Request]):
+        """After the dispatch of the prefill that completes ``reqs``:
+        stamp it, record each request's wait since ``LiveCluster.submit``,
+        then read the sampled first tokens back to the host."""
+        now = obs.clock_ns()
+        for req in reqs:
+            req.dispatch_ns = now
+            if req.submit_ns is not None:
+                obs.record("request.queued", req.submit_ns, now, rid=req.rid)
+        with obs.span("engine.readback", site="prefill",
+                      instance=self.instance_id):
+            toks = np.asarray(sampled)
+        now = obs.clock_ns()
+        for req in reqs:
+            req.first_token_ns = now
+        return toks
+
     def _prefill_single(self, req: Request, extra: Optional[dict]) -> int:
         """Unpadded single-prompt path (modality extras, recurrent or
         enc-dec stacks); scratch sized to the prompt's bucket when the
@@ -391,11 +418,13 @@ class InstanceEngine:
                   if self._attn_only and not extra else self.kv_capacity)
         self.programs["prefill", req.prompt_len, window] += 1
         fresh = init_state(self.cfg, 1, window)
-        with self._mesh_ctx():
-            logits, fresh = self._jit_prefill(self.params, batch, fresh)
-        self._key, sub = jax.random.split(self._key)
-        tok = int(sample_slots(logits, sub, jnp.asarray([slot]),
-                               self.temperature)[0])
+        with obs.span("engine.prefill.dispatch", instance=self.instance_id):
+            with self._mesh_ctx():
+                logits, fresh = self._jit_prefill(self.params, batch, fresh)
+            self._key, sub = jax.random.split(self._key)
+            sampled = sample_slots(logits, sub, jnp.asarray([slot]),
+                                   self.temperature)
+        tok = int(self._read_first_tokens(sampled, [req])[0])
         self.store.merge_slot_rows(slot, fresh, 0, window)
         self._finish_prefill(req, slot, tok)
         return slot
@@ -404,32 +433,35 @@ class InstanceEngine:
                         ) -> Dict[int, int]:
         """Batched bucketed prefill: all items in one jitted call."""
         from repro.stepplan import bucket_len
-        slots = self.free_slots()
-        assert len(slots) >= len(items), \
-            f"instance {self.instance_id}: {len(items)} prefills, " \
-            f"{len(slots)} free slots"
-        for s in slots[:len(items)]:
-            self._clean_slot(s)
-        B = len(items)
-        Bp = bucket_len(B, floor=1)
-        toks = np.zeros((Bp, bucket), np.int32)
-        lens = np.ones((Bp,), np.int32)
-        for i, it in enumerate(items):
-            toks[i, :it.prompt_len] = np.asarray(it.req.prompt_tokens)[0]
-            lens[i] = it.prompt_len
-        fresh = init_state(self.cfg, Bp, bucket)
+        with obs.span("engine.prefill.prep", instance=self.instance_id):
+            slots = self.free_slots()
+            assert len(slots) >= len(items), \
+                f"instance {self.instance_id}: {len(items)} prefills, " \
+                f"{len(slots)} free slots"
+            for s in slots[:len(items)]:
+                self._clean_slot(s)
+            B = len(items)
+            Bp = bucket_len(B, floor=1)
+            toks = np.zeros((Bp, bucket), np.int32)
+            lens = np.ones((Bp,), np.int32)
+            for i, it in enumerate(items):
+                toks[i, :it.prompt_len] = np.asarray(it.req.prompt_tokens)[0]
+                lens[i] = it.prompt_len
+            fresh = init_state(self.cfg, Bp, bucket)
         self.programs["prefill_batched", Bp, bucket] += B
-        with self._mesh_ctx():
-            logits, fresh = self._jit_prefill_batched(
-                self.params, jnp.asarray(toks), fresh, jnp.asarray(lens))
-        self._key, sub = jax.random.split(self._key)
-        # pad rows fold in an unused sentinel slot; their draws are
-        # discarded and never perturb a real slot's stream
-        row_slots = np.full((Bp,), self.num_slots, np.int32)
-        row_slots[:B] = slots[:B]
-        next_toks = np.asarray(sample_slots(logits, sub,
-                                            jnp.asarray(row_slots),
-                                            self.temperature))
+        with obs.span("engine.prefill.dispatch", instance=self.instance_id):
+            with self._mesh_ctx():
+                logits, fresh = self._jit_prefill_batched(
+                    self.params, jnp.asarray(toks), fresh, jnp.asarray(lens))
+            self._key, sub = jax.random.split(self._key)
+            # pad rows fold in an unused sentinel slot; their draws are
+            # discarded and never perturb a real slot's stream
+            row_slots = np.full((Bp,), self.num_slots, np.int32)
+            row_slots[:B] = slots[:B]
+            sampled = sample_slots(logits, sub, jnp.asarray(row_slots),
+                                   self.temperature)
+        next_toks = self._read_first_tokens(sampled,
+                                            [it.req for it in items])
         out: Dict[int, int] = {}
         for i, it in enumerate(items):
             slot = slots[i]
@@ -479,9 +511,10 @@ class InstanceEngine:
         toks = req.prompt_tokens[:, it.start:it.end]
         self.programs["prefill_chunk", it.end - it.start, it.start] += 1
         sub = self.store.extract_slot(slot)
-        with self._mesh_ctx():
-            logits, sub = self._jit_prefill_chunk(self.params, toks, sub,
-                                                  history=it.start)
+        with obs.span("engine.prefill.dispatch", instance=self.instance_id):
+            with self._mesh_ctx():
+                logits, sub = self._jit_prefill_chunk(self.params, toks, sub,
+                                                      history=it.start)
         self.store.merge_slot_rows(slot, sub, it.start, it.end)
         if not it.completes:
             # cursor over the KV ledger: lines materialized so far.  The
@@ -492,8 +525,9 @@ class InstanceEngine:
             return None
         del self.prefilling[slot]
         self._key, sub_key = jax.random.split(self._key)
-        tok = int(sample_slots(logits, sub_key, jnp.asarray([slot]),
-                               self.temperature)[0])
+        sampled = sample_slots(logits, sub_key, jnp.asarray([slot]),
+                               self.temperature)
+        tok = int(self._read_first_tokens(sampled, [req])[0])
         self._finish_prefill(req, slot, tok, ledgered=True)
         return slot
 
@@ -512,14 +546,18 @@ class InstanceEngine:
         tokens = jnp.asarray(self.last_tokens)[:, None]
         t = jnp.asarray(self.lengths)
         self.programs["decode",] += len(self.slot_req)
-        with self._mesh_ctx():
-            logits, self.store.state = self._jit_decode(
-                self.params, tokens, self.store.state, t)
-        self._key, sub = jax.random.split(self._key)
-        # per-slot keys (fold_in by slot index == row index here) keep
-        # sampled tokens invariant to batch compaction on the paged path
-        next_tokens = np.asarray(sample_slots(
-            logits, sub, jnp.arange(self.num_slots), self.temperature))
+        with obs.span("engine.decode.dispatch", instance=self.instance_id):
+            with self._mesh_ctx():
+                logits, self.store.state = self._jit_decode(
+                    self.params, tokens, self.store.state, t)
+            self._key, sub = jax.random.split(self._key)
+            # per-slot keys (fold_in by slot index == row index here) keep
+            # sampled tokens invariant to batch compaction on the paged path
+            sampled = sample_slots(logits, sub, jnp.arange(self.num_slots),
+                                   self.temperature)
+        with obs.span("engine.readback", site="decode",
+                      instance=self.instance_id):
+            next_tokens = np.asarray(sampled)
         self.host_syncs += 1
         out = {}
         for slot, req in list(self.slot_req.items()):
@@ -562,64 +600,72 @@ class InstanceEngine:
                 for slot, tok in self.decode().items():
                     out.setdefault(slot, []).append(tok)
             return out
-        slots = self.active_slots()
-        reqs = [self.slot_req[s] for s in slots]
-        budget = np.asarray([r.max_new_tokens - r.generated for r in reqs],
-                            np.int32)
-        # never scan past the last live row's budget: trailing steps
-        # would only re-freeze dead rows
-        steps = max(1, min(steps, int(budget.max())))
-        t0 = self.lengths[slots].astype(np.int32)
-        # tables cover the lines the scan can reach; padded to a
-        # power-of-two block count so compiles stay O(log window)
-        from repro.stepplan import bucket_len
-        need = -(-min(int(t0.max()) + steps, self.kv_capacity)
-                 // self.store.block_lines)
-        blocks = bucket_len(need, floor=1,
-                            cap=self.store.line_blocks_per_slot)
-        # the slot-affine tables are growth-stable: reuse the uploaded
-        # array until batch membership — (rid, slot) pairs, since a
-        # request can leave and re-enter at a different slot — or the
-        # block bucket changes (rebuilding per token would tax the
-        # default steps=1 path)
-        cache_key = (tuple(slots), tuple(r.rid for r in reqs), blocks)
-        if self._tables_cache is None or self._tables_cache[0] != cache_key:
-            self._tables_cache = (cache_key, jnp.asarray(
-                self.store.decode_block_tables([r.rid for r in reqs],
-                                               blocks)))
-        tables = self._tables_cache[1]
-        key_chain, keys = decode_keys(self._key, steps)
+        with obs.span("engine.decode.prep", instance=self.instance_id):
+            slots = self.active_slots()
+            reqs = [self.slot_req[s] for s in slots]
+            budget = np.asarray([r.max_new_tokens - r.generated
+                                 for r in reqs], np.int32)
+            # never scan past the last live row's budget: trailing steps
+            # would only re-freeze dead rows
+            steps = max(1, min(steps, int(budget.max())))
+            t0 = self.lengths[slots].astype(np.int32)
+            # tables cover the lines the scan can reach; padded to a
+            # power-of-two block count so compiles stay O(log window)
+            from repro.stepplan import bucket_len
+            need = -(-min(int(t0.max()) + steps, self.kv_capacity)
+                     // self.store.block_lines)
+            blocks = bucket_len(need, floor=1,
+                                cap=self.store.line_blocks_per_slot)
+            # the slot-affine tables are growth-stable: reuse the
+            # uploaded array until batch membership — (rid, slot) pairs,
+            # since a request can leave and re-enter at a different slot
+            # — or the block bucket changes (rebuilding per token would
+            # tax the default steps=1 path)
+            cache_key = (tuple(slots), tuple(r.rid for r in reqs), blocks)
+            if (self._tables_cache is None
+                    or self._tables_cache[0] != cache_key):
+                self._tables_cache = (cache_key, jnp.asarray(
+                    self.store.decode_block_tables([r.rid for r in reqs],
+                                                   blocks)))
+            tables = self._tables_cache[1]
+            key_chain, keys = decode_keys(self._key, steps)
+            tok_in = jnp.asarray(self.last_tokens[slots])[:, None]
+            t0_in, slots_in = jnp.asarray(t0), jnp.asarray(slots)
+            budget_in = jnp.asarray(budget)
         self.programs["decode_multi", len(slots), blocks, steps] += len(slots)
-        with self._mesh_ctx():
-            toks_all, self.store.state, emitted = self._jit_decode_multi(
-                self.params, jnp.asarray(self.last_tokens[slots])[:, None],
-                self.store.state, jnp.asarray(t0), jnp.asarray(slots),
-                tables, jnp.asarray(budget), keys)
-        toks_np = np.asarray(toks_all)
-        emitted = np.asarray(emitted)
+        with obs.span("engine.decode.dispatch", instance=self.instance_id):
+            with self._mesh_ctx():
+                toks_all, self.store.state, emitted = self._jit_decode_multi(
+                    self.params, tok_in, self.store.state, t0_in, slots_in,
+                    tables, budget_in, keys)
+        with obs.span("engine.readback", site="decode",
+                      instance=self.instance_id):
+            toks_np = np.asarray(toks_all)
+            emitted = np.asarray(emitted)
         self.host_syncs += 1
-        # consume key splits only for iterations that actually ran (EOS
-        # can empty the batch early; sequential decode would have
-        # stopped splitting there) — fused and per-step paths agree on
-        # the key state the NEXT request samples under
-        self._key = key_chain[int(emitted.max())]
         out = {}
-        for i, slot in enumerate(slots):
-            req = reqs[i]
-            n = int(emitted[i])
-            if n == 0:
-                continue
-            toks = [int(x) for x in toks_np[:n, i]]
-            out[slot] = toks
-            req.generated += n
-            req.output_tokens.extend(toks)
-            self.store.append_line(req.rid, n)
-            self.lengths[slot] += n
-            self.last_tokens[slot] = toks[-1]
-            if req.done or (self.eos_token is not None
-                            and toks[-1] == self.eos_token):
-                req.phase = Phase.DONE
-                self.release(slot)
+        with obs.span("engine.decode.tokens", instance=self.instance_id):
+            # consume key splits only for iterations that actually ran
+            # (EOS can empty the batch early; sequential decode would
+            # have stopped splitting there) — fused and per-step paths
+            # agree on the key state the NEXT request samples under
+            self._key = key_chain[int(emitted.max())]
+            for i, slot in enumerate(slots):
+                req = reqs[i]
+                n = int(emitted[i])
+                if n == 0:
+                    continue
+                toks = [int(x) for x in toks_np[:n, i]]
+                out[slot] = toks
+                req.generated += n
+                req.output_tokens.extend(toks)
+                self.store.append_line(req.rid, n)
+                self.lengths[slot] += n
+                self.last_tokens[slot] = toks[-1]
+                if req.done or (self.eos_token is not None
+                                and toks[-1] == self.eos_token):
+                    req.phase = Phase.DONE
+                    self.release(slot)
         return out
 
     # -- slot management --------------------------------------------------------

@@ -46,6 +46,12 @@ class Request:
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     token_times: List[float] = field(default_factory=list)
+    #: the same moments on the host clock (``repro.obs.clock_ns``), set
+    #: by the live path only: submitted to ``LiveCluster``, the prefill
+    #: that completes the request dispatched, its first token read back
+    submit_ns: Optional[int] = None
+    dispatch_ns: Optional[int] = None
+    first_token_ns: Optional[int] = None
 
     # -- serving state size (bytes of KV/SSM state at current length) -------
     @property

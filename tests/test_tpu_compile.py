@@ -52,9 +52,12 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(fn, *args):
+def _assert_kernel(fn, *args, name):
+    """The program holds the Mosaic kernel, under its ``pallas_call``
+    name (what a device trace shows for the kernel's operation)."""
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert f"%{name}." in text
 
 
 def test_paged_decode_compiles_for_v5e(one_chip):
@@ -64,7 +67,8 @@ def test_paged_decode_compiles_for_v5e(one_chip):
     _assert_kernel(paged_decode_attention_pallas,
                    _spec((SLOTS, 1, H, HD), DT, one_chip), pool, pool,
                    _spec((SLOTS, per_page), jnp.int32, one_chip),
-                   _spec((SLOTS,), jnp.int32, one_chip))
+                   _spec((SLOTS,), jnp.int32, one_chip),
+                   name="paged_decode")
 
 
 def test_flash_prefill_compiles_for_v5e(one_chip):
@@ -73,11 +77,13 @@ def test_flash_prefill_compiles_for_v5e(one_chip):
     _assert_kernel(
         lambda q, k, v: flash_attention_pallas(q, k, v, causal=True,
                                                window=CFG.sliding_window),
-        _spec((1, bucket, H, HD), DT, one_chip), kv, kv)
+        _spec((1, bucket, H, HD), DT, one_chip), kv, kv,
+        name="flash_prefill")
 
 
 def test_dense_decode_compiles_for_v5e(one_chip):
     cache = _spec((SLOTS, KVH, CAP, HD), DT, one_chip)
     _assert_kernel(decode_attention_pallas,
                    _spec((SLOTS, 1, H, HD), DT, one_chip), cache, cache,
-                   _spec((SLOTS,), jnp.int32, one_chip))
+                   _spec((SLOTS,), jnp.int32, one_chip),
+                   name="paged_decode")
