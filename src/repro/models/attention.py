@@ -20,7 +20,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.common import KeyGen, apply_rope, dense_init, rms_norm
-from repro.models.state import KV_HEAD_AXIS, KV_LINE_AXIS, pool_view
+from repro.models.state import (KV_HEAD_AXIS, KV_LINE_AXIS,
+                                stacked_pool_view)
 
 Array = jax.Array
 
@@ -180,19 +181,28 @@ class PagedDecode:
     The decode batch is *compacted*: row ``i`` of the activations is
     request slot ``slots[i]`` of the (full, ``num_slots``-row) cache
     state, and its K/V is read back through ``tables[i]`` — physical
-    line-block ids into the pool view of the cache
+    line-block ids into the pool view of one layer's cache
     (``PagedStore.pool_view`` layout: the head-major ``(B, KVH, W, hd)``
     leaf reshaped to ``(B, KVH, W/block_lines, block_lines, hd)``, block
     id ``slot * W/block_lines + j``).  Replica and free slots are simply
     absent from ``slots``, so they cost nothing.
+
+    The layer scan hands each layer the segment's whole stacked K/V and
+    :meth:`at_layer` names the layer (``layer``, a traced index into the
+    stack) that attention writes and reads.
     """
 
-    __slots__ = ("slots", "tables", "block_lines")
+    __slots__ = ("slots", "tables", "block_lines", "layer")
 
-    def __init__(self, slots: Array, tables: Array, block_lines: int):
+    def __init__(self, slots: Array, tables: Array, block_lines: int,
+                 layer: Optional[Array] = None):
         self.slots = slots            # (Bc,) int32 — state rows of the batch
         self.tables = tables          # (Bc, max_blocks) int32 pool block ids
         self.block_lines = block_lines
+        self.layer = layer            # int32 index into the stacked state
+
+    def at_layer(self, layer: Array) -> "PagedDecode":
+        return PagedDecode(self.slots, self.tables, self.block_lines, layer)
 
 
 def decode_attention(
@@ -343,26 +353,37 @@ def gqa_forward(
                                   t if t is not None else jnp.int32(0))
     elif mode == "decode" and paged is not None:
         # paged hot path (ISSUE 5): the batch is compacted to the active
-        # primary slots; the new K/V line scatters into the full cache at
-        # (slot, t mod W) and attention gathers back ONLY the request's
-        # live line blocks through its block table — decode reads
-        # O(resident lines), not O(num_slots * kv_capacity).
+        # primary slots and ``state`` is the segment's whole stacked K/V
+        # (R, B, KVH, W, hd), carried through the layer scan.  Each row's
+        # new line is written in place at (layer, slot, :, t mod W) — one
+        # dynamic-update-slice per row; a scatter would relayout the whole
+        # stack — and the kernel reads the stack as R*B pages through the
+        # row's block table shifted to this layer's pages.  It fetches
+        # the table's blocks (the bucket covering the row's lines), never
+        # the dense window of every slot.
         assert state is not None and t is not None and S == 1
+        assert paged.layer is not None, "paged decode needs the layer scan"
         from repro import sharding
         from repro.kernels.decode_attention import paged_decode_attention
         # mesh serving (repro.meshserve): pin the compacted query batch's
         # head dim to the slice's model axis so the per-head block gather
         # below stays shard-local (no-op without an active mesh)
         q = sharding.constrain(q, "batch", None, "model", None)
-        cap = state["k"].shape[KV_LINE_AXIS]
+        _, nslots, _, cap, _ = state["k"].shape
         pos = t % cap
-        kc = state["k"].at[paged.slots, :, pos].set(k[:, 0])
-        vc = state["v"].at[paged.slots, :, pos].set(v[:, 0])
+        kc, vc = state["k"], state["v"]
+        for i in range(B):
+            at = (paged.layer, paged.slots[i], 0, pos[i], 0)
+            kc = jax.lax.dynamic_update_slice(
+                kc, k[i, 0, None, None, :, None].astype(kc.dtype), at)
+            vc = jax.lax.dynamic_update_slice(
+                vc, v[i, 0, None, None, :, None].astype(vc.dtype), at)
         bl = paged.block_lines
+        tables = paged.tables + paged.layer * (nslots * (cap // bl))
         lengths = jnp.minimum(t + 1, cap)
         out = paged_decode_attention(
-            q, pool_view(kc, bl), pool_view(vc, bl),
-            paged.tables, lengths, scale=scale, backend=kernel_backend())
+            q, stacked_pool_view(kc, bl), stacked_pool_view(vc, bl),
+            tables, lengths, scale=scale, backend=kernel_backend())
         new_state = dict(state, k=kc, v=vc)
     elif mode == "decode":
         assert state is not None and t is not None

@@ -197,6 +197,8 @@ def apply_segment(cfg: ModelConfig, seg: Segment, params, x: Array,
                   ) -> Tuple[Array, Any, Array]:
     """Scan the periodic body over the repeat dim."""
     has_state = seg_state is not None
+    if has_state and ctx.get("paged") is not None:
+        return _apply_segment_paged(cfg, seg, params, x, seg_state, ctx)
 
     def body(carry, xs):
         xc, aux = carry
@@ -215,6 +217,33 @@ def apply_segment(cfg: ModelConfig, seg: Segment, params, x: Array,
 
     xs = (params, seg_state) if has_state else params
     (x, aux), states = jax.lax.scan(body, (x, jnp.float32(0.0)), xs)
+    return x, states, aux
+
+
+def _apply_segment_paged(cfg: ModelConfig, seg: Segment, params, x: Array,
+                         seg_state, ctx: Dict[str, Any]
+                         ) -> Tuple[Array, Any, Array]:
+    """Paged decode: the stacked K/V rides in the scan's carry and each
+    layer updates its lines in place at its layer index.  A step writes
+    one line per row and layer; threaded through ``xs``/``ys``, each
+    layer's whole cache would be sliced out, relaid out and stacked back
+    instead."""
+    paged = ctx["paged"]
+
+    def body(carry, xs):
+        xc, aux, st = carry
+        lp, layer = xs
+        lctx = dict(ctx, paged=paged.at_layer(layer))
+        st = dict(st)
+        for j, spec in enumerate(seg.specs):
+            xc, st[f"p{j}"], aux_j = apply_layer(cfg, spec, lp[f"p{j}"], xc,
+                                                 st[f"p{j}"], lctx)
+            aux = aux + aux_j
+        return (xc, aux, st), None
+
+    (x, aux, states), _ = jax.lax.scan(
+        body, (x, jnp.float32(0.0), seg_state),
+        (params, jnp.arange(seg.repeats, dtype=jnp.int32)))
     return x, states, aux
 
 

@@ -5,7 +5,9 @@ All states are plain dicts (pytrees) so they stack cleanly under the
 layer-scan and shard with NamedSharding. Every state dict carries only
 arrays; the scalar clock ``t`` lives in the engine, passed per call.
 
-Layout conventions (R = segment repeat dim, added by the model's layer scan):
+Layout conventions (R = segment repeat dim, added by the model's layer scan;
+paged decode carries the stacked K/V whole through the scan, see
+``stacked_pool_view``):
   attention KV : k,v          (B, KVH, W, HD)    W = cache window capacity
                  (head-major: one head's lines are contiguous, the tile
                  the paged decode kernel DMAs)
@@ -52,6 +54,17 @@ def pool_view(cache, block_lines: int):
     + j`` is line block ``j`` of batch row ``b``."""
     B, KVH, W, HD = cache.shape
     return cache.reshape(B, KVH, W // block_lines, block_lines, HD)
+
+
+def stacked_pool_view(stack, block_lines: int):
+    """A segment's stacked K/V leaf ``(R, B, KVH, W, HD)`` as one block
+    pool of ``R * B`` pages ``(R * B, KVH, W/block_lines, block_lines,
+    HD)`` (a reshape, no copy): block ``j`` of batch row ``b`` in layer
+    ``r`` is pool block ``(r * B + b) * W/block_lines + j``, so a layer's
+    block table is its :func:`pool_view` table plus ``r * B *
+    W/block_lines``."""
+    R, B, KVH, W, HD = stack.shape
+    return stack.reshape(R * B, KVH, W // block_lines, block_lines, HD)
 
 
 def line_index(slot, pos, axis: int) -> tuple:

@@ -8,18 +8,25 @@ Covers the three bit-identity contracts of the issue:
 * ``decode_multi(steps=k)`` == k sequential ``decode()`` calls
   token-for-token, including EOS mid-scan,
 * the compacted batch == the full batch when replica slots are resident,
+* the in-place writes into the stacked K/V == the dense oracle, bit for
+  bit over every layer and slot,
 
 plus the planner's fuse gating, the repriced ``PerfModel.plan_time``
 (block-granular gather bytes, per-dispatch amortization), and the
 fused LiveCluster run.
 """
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.models import init_params
+from repro.kernels.decode_attention import decode_attention_pallas
+from repro.models import attention, decode_multi, decode_step, init_params
+from repro.models import init_state
 from repro.models.attention import set_kernel_backend
 from repro.scheduling import LiveCluster
 from repro.scheduling.baselines import VLLMScheduler
@@ -134,6 +141,99 @@ def test_empty_decode_skips_jitted_call(setup):
     eng.import_slot(0, src.export_slot(slot), req, as_replica_of=(1, slot))
     assert eng.replica_of and eng.decode() == {}
     assert eng.host_syncs == 0
+
+
+# ---------------------------------------------------------------------------
+# in-place writes into the stacked K/V: every layer, every slot
+# ---------------------------------------------------------------------------
+
+W, BL = 32, 8                               # 4 line blocks per slot
+SLOTS = jnp.asarray([3, 0, 2], jnp.int32)   # compacted rows, not sorted
+
+
+def _planted(cfg, layers, num_slots):
+    """A ``layers``-deep stack whose cache holds different K/V in every
+    layer, slot and line, so a block table offset to the wrong layer
+    reads other values."""
+    cfg = dataclasses.replace(cfg, num_layers=layers,
+                              block_pattern=("attn",) * layers)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    state = init_state(cfg, num_slots, W)
+    leaves, tree = jax.tree_util.tree_flatten(state)
+    keys = jax.random.split(jax.random.PRNGKey(5), len(leaves))
+    state = jax.tree_util.tree_unflatten(tree, [
+        jax.random.normal(k, a.shape, a.dtype) for k, a in zip(keys, leaves)])
+    return cfg, params, state
+
+
+def _dense_multi(cfg, params, toks, state, t, budget, steps):
+    """``decode_multi``'s contract through the dense decode path: each
+    step decodes the compacted rows' own caches (gathered by slot) over
+    the whole window and writes them back."""
+    alive = jnp.ones(t.shape, bool)
+    emitted = jnp.zeros(t.shape, jnp.int32)
+    out = []
+    for _ in range(steps):
+        sub = jax.tree_util.tree_map(lambda a: a[:, SLOTS], state["layers"])
+        logits, sub = decode_step(cfg, params, toks,
+                                  dict(state, layers=sub), t)
+        state = dict(state, layers=jax.tree_util.tree_map(
+            lambda a, b: a.at[:, SLOTS].set(b), state["layers"],
+            sub["layers"]))
+        nxt = jnp.where(alive, jnp.argmax(logits, -1).astype(jnp.int32),
+                        toks[:, 0])
+        emitted = emitted + alive
+        t = t + alive
+        alive = alive & (emitted < budget)
+        out.append(nxt)
+        toks = nxt[:, None]
+    return jnp.stack(out), state, emitted
+
+
+def _kernel_dense_attention(q, k_cache, v_cache, *, scale, valid):
+    """The dense decode attention in the paged kernel's own arithmetic:
+    the kernel over each row's whole window through the identity table,
+    in blocks of the paged path's ``block_lines``."""
+    return decode_attention_pallas(q, k_cache, v_cache, valid.sum(-1),
+                                   scale=scale, block_k=BL, interpret=True)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "interpret"])
+@pytest.mark.parametrize("t0,budget,steps", [
+    # one step: a row starting block 1, a row past the ring's end
+    ((5, BL, W + 3), (9, 9, 9), 1),
+    # six steps: crosses a block boundary, wraps the ring, and row 1
+    # dies after two steps (its frozen line is rewritten from then on)
+    ((3, BL - 2, W - 2), (6, 2, 5), 6),
+], ids=["step", "multi-row-dies"])
+def test_paged_in_place_writes_match_dense_oracle(setup, monkeypatch,
+                                                  backend, t0, budget,
+                                                  steps):
+    cfg, params, state = _planted(setup[0], layers=3, num_slots=4)
+    toks = jnp.asarray([[5], [9], [11]], jnp.int32)
+    t0, budget = (jnp.asarray(x, jnp.int32) for x in (t0, budget))
+    tables = SLOTS[:, None] * (W // BL) + jnp.arange(W // BL)[None]
+    keys = jax.random.split(jax.random.PRNGKey(1), steps)
+    set_kernel_backend(backend)
+    try:
+        got = jax.jit(functools.partial(decode_multi, cfg, block_lines=BL))(
+            params, toks, state, t0, SLOTS, tables, budget, keys)
+    finally:
+        set_kernel_backend("auto")
+    if backend == "interpret":
+        monkeypatch.setattr(attention, "decode_attention",
+                            _kernel_dense_attention)
+    want = _dense_multi(cfg, params, toks, state, t0, budget, steps)
+    assert jnp.array_equal(got[0], want[0])
+    assert jnp.array_equal(got[2], want[2])
+    assert int(want[2][1]) == min(2, steps)
+    for a, b in zip(jax.tree_util.tree_leaves(got[1]),
+                    jax.tree_util.tree_leaves(want[1])):
+        assert a.shape[0] == 3 and jnp.array_equal(a, b)
+    # the slot outside the batch is untouched
+    for a, b in zip(jax.tree_util.tree_leaves(got[1]),
+                    jax.tree_util.tree_leaves(state)):
+        assert jnp.array_equal(a[:, 1], b[:, 1])
 
 
 # ---------------------------------------------------------------------------

@@ -4,13 +4,17 @@ Interpret mode runs a kernel body on the CPU but never asks Mosaic
 whether the block shapes tile the chip's memory; these tests do, by
 compiling against a described (not attached) v5e.  Nothing runs, so the
 tests check only that each program compiles and holds the kernel
-(``tpu_custom_call``).
+(``tpu_custom_call``), and what the compiled decode program does with
+its KV state.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker
 imports this file.
 """
+import dataclasses
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +22,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.models import decode_multi, init_params, init_state
+from repro.models.attention import set_kernel_backend
 from repro.kernels.decode_attention import (decode_attention_pallas,
                                             paged_decode_attention_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -87,3 +93,83 @@ def test_dense_decode_compiles_for_v5e(one_chip):
                    _spec((SLOTS, 1, H, HD), DT, one_chip), cache, cache,
                    _spec((SLOTS,), jnp.int32, one_chip),
                    name="paged_decode")
+
+
+def _computations(text):
+    """HLO computation name -> its body lines; the entry computation
+    under ``"ENTRY"``."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+@pytest.mark.parametrize("arch,layers", [
+    ("starcoder2-3b", 2), ("phi3-medium-14b", 2),
+    ("starcoder2-3b", 30), ("phi3-medium-14b", 10)])
+def test_paged_decode_writes_kv_in_place_for_v5e(one_chip, arch, layers):
+    """The served ``decode_multi`` (pallas backend, 8 slots x 4,096
+    lines, ``block_lines`` 16) changes one line per row per layer, so no
+    operation whose result is a layer's or the stack's K/V may copy or
+    relayout it: no ``copy``, no ``copy*`` fusion, no scatter.  The
+    donated state is aliased to the output.  (A compile-time prefetch of
+    a stack small enough for the chip's on-core memory is an async
+    ``copy-start``/``copy-done`` into that memory, not a relayout; at 2
+    layers of starcoder2-3b the compiler makes one.)"""
+    cfg = get_config(arch)
+    cfg = dataclasses.replace(cfg, num_layers=layers,
+                              block_pattern=("attn",) * layers)
+    kvh, rows, steps, bl = cfg.num_kv_heads, 4, 2, 16
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _spec(a.shape, a.dtype, one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    state = described(jax.eval_shape(lambda: init_state(cfg, SLOTS, CAP)))
+    rows_i32 = _spec((rows,), jnp.int32, one_chip)
+    fn = jax.jit(functools.partial(decode_multi, cfg, block_lines=bl),
+                 donate_argnums=(2,))
+    set_kernel_backend("pallas")
+    try:
+        text = fn.lower(
+            params, _spec((rows, 1), jnp.int32, one_chip), state, rows_i32,
+            rows_i32, _spec((rows, CAP // bl), jnp.int32, one_chip),
+            rows_i32, _spec((steps, 2), jnp.uint32, one_chip)
+        ).compile().as_text()
+    finally:
+        set_kernel_backend("auto")
+    assert "%paged_decode." in text
+
+    kv = rf"bf16\[(?:\d+,)?{SLOTS},{kvh},{CAP},{cfg.head_dim}\]"
+    comps = _computations(text)
+    bad = []
+    for lines in comps.values():
+        for line in lines:
+            m = re.match(rf"\s*(?:ROOT )?%(\S+) = {kv}\S* ([\w-]+)\(", line)
+            if not m:
+                continue
+            name, op = m.groups()
+            called = re.search(r"calls=%([\w.-]+)", line)
+            body = comps.get(called.group(1), []) if called else []
+            if (op in ("copy", "scatter")
+                    or (op == "fusion" and (name.startswith("copy")
+                                            or any(" scatter(" in b
+                                                   for b in body)))):
+                bad.append(f"{name} = {op}")
+    assert not bad, bad
+
+    # every K/V stack the program takes in is aliased to an output
+    kv_params = {int(n) for n in re.findall(
+        rf"= {kv}\S* parameter\((\d+)\)", "\n".join(comps["ENTRY"]))}
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    aliased = {int(n) for n in re.findall(r"\((\d+), \{\}", alias.group(1))}
+    assert len(kv_params) == 2 and kv_params <= aliased
