@@ -17,6 +17,7 @@ from repro.configs.base import (  # noqa: F401  (re-exported)
     ModelConfig,
     MoEConfig,
     XLSTMConfig,
+    YarnConfig,
 )
 
 _ARCH_MODULES = {

@@ -33,6 +33,25 @@ class MoEConfig:
     moe_layer_offset: int = 0
     first_dense_layers: int = 0       # DeepSeek-V3: first k layers are dense
     first_dense_d_ff: int = 0
+    # routing: the top-k of a "softmax" or "sigmoid" over the router's
+    # outputs, chosen by score (+ a learned per-expert selection bias,
+    # DeepSeek-V3's noaux_tc) within the ``topk_group`` best of
+    # ``n_group`` expert groups.  The chosen scores, without the bias,
+    # are the weights: normalised to sum 1, then scaled by
+    # ``routed_scale``.
+    scoring: str = "softmax"
+    selection_bias: bool = False
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0
+    # routed experts whose weights this layer holds: experts
+    # [0, experts_held) of the router's ``num_experts`` (0 = all); what
+    # experts held elsewhere would add is left out
+    experts_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -48,6 +67,19 @@ class MLAConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V3 publishes it
+    (``rope_scaling`` of type ``yarn``)."""
+
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -122,6 +154,7 @@ class ModelConfig:
     use_rope: bool = True
     abs_pos: str = "none"              # "none" | "sinusoidal" (added at embed)
     sliding_window: Optional[int] = None  # architecture's own native window
+    yarn: Optional[YarnConfig] = None     # YaRN scaling of the MLA rope dims
     # long-context decode policy: window applied only for the long_500k shape
     long_context_window: int = 8192
     # norm / activation
@@ -238,7 +271,7 @@ class ModelConfig:
             mult = 3 if self.activation == "swiglu" else 2
             return mult * d * dff
         mult = 3 if self.activation == "swiglu" else 2
-        n_experts = m.top_k if active_only else m.num_experts
+        n_experts = m.top_k if active_only else m.held
         n = n_experts * mult * d * m.expert_d_ff + d * m.num_experts  # router
         if m.num_shared_experts:
             n += m.num_shared_experts * mult * d * (m.shared_d_ff or m.expert_d_ff)
@@ -301,6 +334,9 @@ class ModelConfig:
                 first_dense_d_ff=256 if self.moe.first_dense_d_ff else 0,
                 moe_layer_period=1,
                 moe_layer_offset=0,
+                n_group=min(self.moe.n_group, 2),
+                topk_group=1,
+                experts_held=0,
             )
         mla = None
         if self.mla is not None:

@@ -1,11 +1,13 @@
-"""DeepSeek-V3 671B [arXiv:2412.19437] — MLA attention (latent KV),
-1 shared + 256 routed experts top-8, first 3 layers dense, MTP head.
+"""DeepSeek-V3 671B [arXiv:2412.19437] — MLA attention (latent KV) with
+YaRN rope, 1 shared + 256 routed experts top-8 (sigmoid scores, a
+selection bias, 4 of 8 groups, weights x 2.5), first 3 layers dense,
+MTP head.
 
 MLA means the serving state is the compressed latent c_kv (512) + rope key
 (64) per token — ~14x smaller than full 128-head KV. This makes AcceLLM's
 redundant-KV copies especially cheap (see DESIGN.md §4).
 """
-from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
+from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, YarnConfig
 
 CONFIG = ModelConfig(
     name="deepseek-v3-671b",
@@ -19,6 +21,9 @@ CONFIG = ModelConfig(
     d_ff=2048,         # routed expert intermediate size
     vocab_size=129280,
     attention_kind="mla",
+    rms_norm_eps=1e-6,
+    yarn=YarnConfig(factor=40.0, original_max=4096, beta_fast=32.0,
+                    beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0),
     activation="swiglu",
     mla=MLAConfig(
         q_lora_rank=1536,
@@ -35,6 +40,11 @@ CONFIG = ModelConfig(
         shared_d_ff=2048,
         first_dense_layers=3,
         first_dense_d_ff=18432,
+        scoring="sigmoid",
+        selection_bias=True,
+        n_group=8,
+        topk_group=4,
+        routed_scale=2.5,
     ),
     mtp_depth=1,
 )

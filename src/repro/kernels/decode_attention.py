@@ -17,6 +17,12 @@ sequential, online-softmax accumulation in VMEM scratch. Invalid (not yet
 written) cache slots are masked via the per-request ``length`` scalar,
 prefetched to SMEM with the block tables.  The dense kernel is the paged
 kernel over the identity table.
+
+MLA (``paged_mla_decode``): every head shares one line ``[latent, roped
+key, zero pad]`` per token (``models.state.mla_line_width``), so the
+grid is (batch, line blocks): each step fetches one ``(block_lines,
+width)`` block through the same scalar-prefetched tables and scores it
+against all heads' absorbed queries at once.
 """
 from __future__ import annotations
 
@@ -231,3 +237,129 @@ def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths,
     out = decode_attention(q, gathered_k, gathered_v, scale=scale,
                            valid=valid)
     return out if squeeze else out[:, 0]
+
+
+# ---------------------------------------------------------------------------
+# MLA: latent lines shared by every head
+# ---------------------------------------------------------------------------
+
+
+def _paged_mla_kernel(lengths_ref, tables_ref, q_ref, line_ref, o_ref,
+                      acc_ref, m_ref, l_ref, *,
+                      scale: float, lat: int, block_lines: int,
+                      max_blocks: int):
+    b = pl.program_id(0)
+    ki = pl.program_id(1)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    length = lengths_ref[b]
+    k_start = ki * block_lines
+
+    @pl.when(k_start < length)
+    def _compute():
+        lines = line_ref[0, 0]                       # (block_lines, width)
+        s = jax.lax.dot_general(
+            q_ref[0], lines, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (H, block_lines)
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_pos < length, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        p = jnp.exp(s - m_new[:, None])
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
+        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+            p.astype(lines.dtype), lines[:, :lat], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(ki == max_blocks - 1)
+    def _finalize():
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+
+
+def paged_mla_decode_pallas(
+    q: jax.Array,             # (B, H, width): [q W_UK, q_rope, 0]
+    pool: jax.Array,          # (P, blocks_per_page, block_lines, width)
+    block_tables: jax.Array,  # (B, max_blocks) int32 — pool block ids
+    lengths: jax.Array,       # (B,) int32 — valid lines per request
+    *,
+    lat: int,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """MLA decode attention over paged lines ``[latent, roped key, 0]``:
+    softmax over the row's lines of ``scale * q . line``, read back as
+    the weighted sum of the lines' first ``lat`` columns (B, H, lat).
+    Block id ``i`` is line block ``i % blocks_per_page`` of page ``i //
+    blocks_per_page``, as in :func:`paged_decode_attention_pallas`."""
+    B, H, width = q.shape
+    _, per_page, block_lines, _ = pool.shape
+    max_blocks = block_tables.shape[1]
+
+    kernel = functools.partial(
+        _paged_mla_kernel, scale=scale, lat=lat, block_lines=block_lines,
+        max_blocks=max_blocks)
+
+    def line_block(b, ki, lens, tabs):
+        blk = tabs[b, ki]
+        return (blk // per_page, blk % per_page, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, max_blocks),
+        in_specs=[
+            pl.BlockSpec((1, H, width), lambda b, ki, lens, tabs: (b, 0, 0)),
+            pl.BlockSpec((1, 1, block_lines, width), line_block),
+        ],
+        out_specs=pl.BlockSpec((1, H, lat),
+                               lambda b, ki, lens, tabs: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((H, lat), jnp.float32),
+            pltpu.VMEM((H,), jnp.float32),
+            pltpu.VMEM((H,), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, lat), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="paged_mla_decode",
+    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32), q, pool)
+
+
+def paged_mla_decode_ref(q, pool, block_tables, lengths, *, lat: int,
+                         scale: float):
+    """jnp oracle: gather each row's line blocks, then dense float32
+    attention over its first ``lengths`` lines."""
+    _, per_page, bl, width = pool.shape
+    B, M = block_tables.shape
+    page, blk = block_tables // per_page, block_tables % per_page
+    lines = pool[page, blk].reshape(B, M * bl, width).astype(jnp.float32)
+    s = jnp.einsum("bhc,bwc->bhw", q.astype(jnp.float32), lines) * scale
+    valid = jnp.arange(M * bl)[None] < lengths[:, None]
+    p = jax.nn.softmax(jnp.where(valid[:, None], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhw,bwl->bhl", p, lines[..., :lat]).astype(q.dtype)
+
+
+def paged_mla_decode(q, pool, block_tables, lengths, *, lat: int,
+                     scale: float, backend: str = "jnp") -> jax.Array:
+    """Backend dispatcher for the MLA paged decode hot path (``"pallas"``,
+    ``"interpret"`` or the ``"jnp"`` gather oracle); all three read the
+    lines only through the block tables."""
+    if backend == "jnp":
+        return paged_mla_decode_ref(q, pool, block_tables, lengths, lat=lat,
+                                    scale=scale)
+    assert backend in ("pallas", "interpret"), backend
+    return paged_mla_decode_pallas(q, pool, block_tables, lengths, lat=lat,
+                                   scale=scale,
+                                   interpret=backend == "interpret")

@@ -252,7 +252,7 @@ def state_pspecs(cfg: ModelConfig, state_shape, shape: InputShape,
         if not batch_ok and sd is not None and nd > sd \
                 and _div(shape_[sd], b_n):
             spec[sd] = b_ax                     # shard KV seq over data
-        if tail in ("c_kv", "k_rope") and nd > sd and spec[sd] is None \
+        if tail == "c_kv" and nd > sd and spec[sd] is None \
                 and _div(shape_[sd], model_n):
             # MLA latent cache: shard the SEQUENCE dim over the model axis
             # (flash-decode-style sharded-KV; §Perf iteration 5). All heads

@@ -1,11 +1,15 @@
 """Attention: GQA (RoPE, optional sliding window) and MLA (DeepSeek latent).
 
-Three execution paths:
+Four execution paths:
   * full   — train / prefill over S tokens: chunked online-softmax "flash"
              in pure jnp (lax.scan over KV blocks), so the S x S score matrix
              is never materialized. On TPU the Pallas kernel in
              ``repro.kernels.flash_attention`` replaces this (same math).
   * decode — single query token against the KV cache: two einsums + softmax.
+  * paged  — the serving decode step: each row writes its new line in
+             place in the carried stacked cache and a Pallas kernel reads
+             the row's lines through its block table (GQA K/V:
+             ``paged_decode``; MLA latent lines: ``paged_mla_decode``).
   * cross  — encoder-decoder cross attention (full or cached decode).
 
 KV caches use ring-buffer indexing when capacity < logical position count.
@@ -19,9 +23,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import KeyGen, apply_rope, dense_init, rms_norm
-from repro.models.state import (KV_HEAD_AXIS, KV_LINE_AXIS,
-                                stacked_pool_view)
+from repro.models.common import (KeyGen, apply_rope, dense_init, rms_norm,
+                                 yarn_mscale)
+from repro.models.state import (KV_HEAD_AXIS, KV_LINE_AXIS, mla_line_width,
+                                stacked_latent_pool_view, stacked_pool_view)
 
 Array = jax.Array
 
@@ -176,7 +181,7 @@ def flash_attention(
 
 
 class PagedDecode:
-    """Paged-decode context threaded through the layer stack (ISSUE 5).
+    """Paged-decode context threaded through the layer stack.
 
     The decode batch is *compacted*: row ``i`` of the activations is
     request slot ``slots[i]`` of the (full, ``num_slots``-row) cache
@@ -445,10 +450,10 @@ def init_mla(key, cfg: ModelConfig, dtype):
     qk, rope, nope, vd = m.qk_head_dim, m.qk_rope_head_dim, m.qk_nope_head_dim, m.v_head_dim
     return {
         "wq_a": dense_init(kg(), d, m.q_lora_rank, dtype),
-        "q_norm": jnp.ones((m.q_lora_rank,), dtype),
+        "norm_q": jnp.ones((m.q_lora_rank,), dtype),
         "wq_b": dense_init(kg(), m.q_lora_rank, h * qk, dtype),
         "wkv_a": dense_init(kg(), d, m.kv_lora_rank + rope, dtype),
-        "kv_norm": jnp.ones((m.kv_lora_rank,), dtype),
+        "norm_kv": jnp.ones((m.kv_lora_rank,), dtype),
         "wkv_b": dense_init(kg(), m.kv_lora_rank, h * (nope + vd), dtype),
         "wo": dense_init(kg(), h * vd, d, dtype),
     }
@@ -456,20 +461,83 @@ def init_mla(key, cfg: ModelConfig, dtype):
 
 def _mla_qkv_latent(cfg, params, x, positions):
     """Shared projections: roped q (split nope/rope), normed latent, roped
-    shared key-rope."""
+    shared key-rope.  The rope dims rotate in rotate-half pairs (the
+    published code interleaves them; random weights cannot tell the
+    two apart), with YaRN's frequencies where the config has them."""
     m = cfg.mla
     B, S, _ = x.shape
     h = cfg.num_heads
     q = (x @ params["wq_a"])
-    q = rms_norm(q, params["q_norm"], cfg.rms_norm_eps) @ params["wq_b"]
+    q = rms_norm(q, params["norm_q"], cfg.rms_norm_eps) @ params["wq_b"]
     q = q.reshape(B, S, h, m.qk_head_dim)
     q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.yarn)
     kv = x @ params["wkv_a"]
     c_kv, k_rope = kv[..., : m.kv_lora_rank], kv[..., m.kv_lora_rank:]
-    c_kv = rms_norm(c_kv, params["kv_norm"], cfg.rms_norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    c_kv = rms_norm(c_kv, params["norm_kv"], cfg.rms_norm_eps)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        cfg.yarn)[:, :, 0]
     return q_nope, q_rope, c_kv, k_rope
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale of MLA scores: ``qk_head_dim ** -0.5``, times YaRN's
+    ``mscale(factor, mscale_all_dim) ** 2`` where the rope is scaled."""
+    scale = cfg.mla.qk_head_dim ** -0.5
+    if cfg.yarn is not None:
+        scale *= yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_decode_paged(paged: PagedDecode, state, line, q_line, t, scale,
+                      lat: int):
+    """Paged hot path, as GQA's: ``state["c_kv"]`` is the segment's
+    stacked line leaf (R, B, W, width), carried through the layer
+    scan.  Each row's new line is written in place at (layer, slot, t mod
+    W) — one dynamic-update-slice per row — and the kernel reads the
+    stack as R*B pages through the row's table shifted to this layer,
+    each line block once for every head.  Returns the latent output
+    (B, H, lat) and the state."""
+    assert line.shape[1] == 1
+    assert paged.layer is not None, "paged decode needs the layer scan"
+    from repro.kernels.decode_attention import paged_mla_decode
+    stack = state["c_kv"]
+    _, nslots, cap, _ = stack.shape
+    pos = t % cap
+    for i in range(line.shape[0]):
+        stack = jax.lax.dynamic_update_slice(
+            stack, line[i, 0, None, None, None].astype(stack.dtype),
+            (paged.layer, paged.slots[i], pos[i], 0))
+    bl = paged.block_lines
+    tables = paged.tables + paged.layer * (nslots * (cap // bl))
+    ctx_lat = paged_mla_decode(
+        q_line, stacked_latent_pool_view(stack, bl), tables,
+        jnp.minimum(t + 1, cap), lat=lat, scale=scale,
+        backend=kernel_backend())
+    return ctx_lat, dict(state, c_kv=stack)
+
+
+def _mla_decode_dense(state, line, q_line, t, scale, lat: int):
+    """Dense oracle path over every slot's whole window.  Sharding
+    (§Perf iteration 5): the line cache shards its SEQ dim over the model
+    axis (all heads share the latent, so head-sharding it is
+    impossible); q/scores replicate heads and the softmax/read
+    contractions psum tiny partials instead of all-gathering the cache
+    every layer."""
+    from repro import sharding as _sh
+    cap = state["c_kv"].shape[1]
+    cache = _sh.constrain(ring_write(state["c_kv"], line, t, cap),
+                          "batch", "model", None)
+    q_line = _sh.constrain(q_line, "batch", None, None)
+    lines = cache.astype(jnp.float32)
+    s = jnp.einsum("bhc,bwc->bhw", q_line.astype(jnp.float32), lines)
+    valid = ring_valid(t + line.shape[1], cap)
+    if valid.ndim == 1:
+        valid = valid[None]
+    p = jax.nn.softmax(jnp.where(valid[:, None, :], s * scale, NEG_INF),
+                       axis=-1)
+    ctx_lat = jnp.einsum("bhw,bwl->bhl", p, lines[..., :lat])
+    return ctx_lat, dict(state, c_kv=cache)
 
 
 def mla_forward(
@@ -487,15 +555,19 @@ def mla_forward(
     history: int = 0,
     paged: Optional[PagedDecode] = None,
 ):
-    assert paged is None, \
-        "paged decode gathers per-head K/V blocks; the MLA latent cache " \
-        "decodes through the absorbed dense path"
     m = cfg.mla
     B, S, _ = x.shape
     h = cfg.num_heads
     nope, rope, vd = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
-    scale = 1.0 / math.sqrt(nope + rope)
+    scale = mla_scale(cfg)
     q_nope, q_rope, c_kv, k_rope = _mla_qkv_latent(cfg, params, x, positions)
+
+    lat = m.kv_lora_rank
+    # one cache line per token (``state["c_kv"]``): the normed latent, the
+    # roped key shared by every head, zeros to the line width
+    pad = mla_line_width(cfg) - lat - rope
+    line = jnp.concatenate(
+        [c_kv, k_rope, jnp.zeros((B, S, pad), c_kv.dtype)], -1)
 
     if mode == "full":
         # materialized path (prefill/train): expand latent to per-head K/V
@@ -507,14 +579,13 @@ def mla_forward(
             # chunked-prefill continuation: expand the cached latent rows
             # [0, history) (already normed + roped) the same way
             assert state is not None and causal
-            ckv_h = state["c_kv"][:, :history].astype(c_kv.dtype)
-            krope_h = state["k_rope"][:, :history].astype(k_rope.dtype)
-            kvb_h = (ckv_h @ params["wkv_b"]).reshape(B, history, h,
-                                                      nope + vd)
+            hist = state["c_kv"][:, :history].astype(c_kv.dtype)
+            kvb_h = (hist[..., :lat] @ params["wkv_b"]).reshape(
+                B, history, h, nope + vd)
             k_h = jnp.concatenate(
                 [kvb_h[..., :nope],
-                 jnp.broadcast_to(krope_h[:, :, None], (B, history, h, rope))],
-                -1)
+                 jnp.broadcast_to(hist[:, :, None, lat:lat + rope],
+                                  (B, history, h, rope))], -1)
             k = jnp.concatenate([k_h, k], axis=1)
             v = jnp.concatenate([kvb_h[..., nope:], v], axis=1)
         q = jnp.concatenate([q_nope, q_rope], -1)
@@ -524,41 +595,29 @@ def mla_forward(
         if update_cache and state is not None:
             cap = state["c_kv"].shape[1]
             t0 = t if t is not None else jnp.int32(0)
-            new_state = dict(state)
-            new_state["c_kv"] = ring_write(state["c_kv"], c_kv, t0, cap)
-            new_state["k_rope"] = ring_write(state["k_rope"], k_rope, t0, cap)
+            new_state = dict(state, c_kv=ring_write(state["c_kv"], line, t0,
+                                                    cap))
     elif mode == "decode":
-        # absorbed path: score & read in latent space.
-        # Sharding (§Perf iteration 5): the latent cache shards its SEQ dim
-        # over the model axis (all heads share the latent, so head-sharding
-        # it is impossible); q/scores replicate heads for the attention ops
-        # and the softmax/read contractions psum tiny partials instead of
-        # all-gathering the 2x(B,W,512) cache every layer.
-        from repro import sharding as _sh
+        # absorbed path: W_UK folds into q, so the scores and the read-back
+        # stay in latent space and every head reads the same lines; W_UV
+        # then lifts the latent output
         assert state is not None and t is not None
-        cap = state["c_kv"].shape[1]
-        ckv_c = ring_write(state["c_kv"], c_kv, t, cap)
-        krope_c = ring_write(state["k_rope"], k_rope, t, cap)
-        ckv_c = _sh.constrain(ckv_c, "batch", "model", None)
-        krope_c = _sh.constrain(krope_c, "batch", "model", None)
-        valid = ring_valid(t + S, cap)
-        wkv_b = params["wkv_b"].reshape(m.kv_lora_rank, h, nope + vd)
+        wkv_b = params["wkv_b"].reshape(lat, h, nope + vd)
         w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]
-        # absorb W_UK into q: (B,1,H,nope) x (lat,H,nope) -> (B,H,lat)
-        q_lat = jnp.einsum("bshn,lhn->bhl", q_nope.astype(jnp.float32),
-                           w_uk.astype(jnp.float32))
-        q_lat = _sh.constrain(q_lat, "batch", None, None)
-        s = jnp.einsum("bhl,bwl->bhw", q_lat, ckv_c.astype(jnp.float32))
-        s += jnp.einsum("bshr,bwr->bhw", q_rope.astype(jnp.float32),
-                        krope_c.astype(jnp.float32))
-        if valid.ndim == 1:
-            valid = valid[None]
-        s = jnp.where(valid[:, None, :], s * scale, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        ctx_lat = jnp.einsum("bhw,bwl->bhl", p, ckv_c.astype(jnp.float32))
-        out = jnp.einsum("bhl,lhv->bhv", ctx_lat, w_uv.astype(jnp.float32))
+        q_lat = jnp.einsum("bshn,lhn->bhl", q_nope, w_uk,
+                           preferred_element_type=jnp.float32)
+        q_line = jnp.concatenate(
+            [q_lat, q_rope[:, 0].astype(jnp.float32),
+             jnp.zeros((B, h, pad), jnp.float32)], -1)      # (B,H,width)
+        if paged is not None:
+            ctx_lat, new_state = _mla_decode_paged(
+                paged, state, line, q_line.astype(x.dtype), t, scale, lat)
+        else:
+            ctx_lat, new_state = _mla_decode_dense(
+                state, line, q_line, t, scale, lat)
+        out = jnp.einsum("bhl,lhv->bhv", ctx_lat, w_uv,
+                         preferred_element_type=jnp.float32)
         out = out[:, None].astype(x.dtype)  # (B,1,H,vd)
-        new_state = dict(state, c_kv=ckv_c, k_rope=krope_c)
     else:
         raise ValueError(mode)
 

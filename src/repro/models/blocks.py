@@ -149,7 +149,9 @@ def apply_layer(cfg: ModelConfig, spec: LayerSpec, params, x: Array,
         x = _sp(x, ctx)
         f_in = rms_norm(x, params["norm2"], cfg.rms_norm_eps)
         if spec.is_moe:
-            h, aux = moe_forward(cfg, params["ffn"], f_in)
+            # serving (every call that fills the caches) drops no token
+            h, aux = moe_forward(cfg, params["ffn"], f_in,
+                                 dropless=ctx["update_cache"])
         else:
             h = dense_ffn(cfg, params["ffn"], f_in)
         x = x + rs * h

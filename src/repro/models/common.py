@@ -1,6 +1,8 @@
 """Shared model-building primitives (pure functional, pytree params)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -63,18 +65,50 @@ def layer_norm(x: Array, weight: Array, bias: Array, eps: float) -> Array:
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(head_dim: int, theta: float) -> Array:
+def rope_freqs(head_dim: int, theta: float, yarn=None) -> Array:
+    """Inverse frequencies of the ``head_dim/2`` rotary pairs; with a
+    :class:`~repro.configs.base.YarnConfig`, YaRN's blend: pairs that
+    turn fewer than ``beta_slow`` times over the original context are
+    interpolated (divided by ``factor``), those that turn more than
+    ``beta_fast`` times keep their frequency, with a linear ramp between
+    (DeepSeek-V3's ``DeepseekV3YarnRotaryEmbedding``)."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is None:
+        return inv
+
+    def dim_of(rotations):
+        return (head_dim * math.log(yarn.original_max
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    hi = min(math.ceil(dim_of(yarn.beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo) / (hi - lo),
+                    0.0, 1.0)
+    return inv / yarn.factor * ramp + inv * (1.0 - ramp)
 
 
-def apply_rope(x: Array, positions: Array, theta: float) -> Array:
-    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term ``0.1 * mscale * ln(factor) +
+    1`` (1 for no scaling)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x: Array, positions: Array, theta: float, yarn=None) -> Array:
+    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
+    Rotate-half pairing (dims i and i + head_dim/2)."""
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)                       # (hd/2,)
+    freqs = rope_freqs(head_dim, theta, yarn)                 # (hd/2,)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., seq, hd/2)
     angles = angles[..., None, :]                             # (..., seq, 1, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

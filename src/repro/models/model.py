@@ -344,7 +344,7 @@ def decode_step_paged(cfg: ModelConfig, params, tokens: Array, state,
                       t: Array, slots: Array, tables: Array,
                       block_lines: int, long_context: bool = False
                       ) -> Tuple[Array, Any]:
-    """One *compacted, paged* decode step (ISSUE 5): ``decode_step``
+    """One *compacted, paged* decode step: ``decode_step``
     with a :class:`~repro.models.attention.PagedDecode` context.
 
     ``tokens`` (Bc, 1) / ``t`` (Bc,) cover only the active primary
@@ -353,8 +353,8 @@ def decode_step_paged(cfg: ModelConfig, params, tokens: Array, state,
     the pool view (``PagedStore.decode_block_tables``).  Attention
     writes the new KV line at (slot, t mod W) and reads back through the
     block tables, so replica/free slots and dead cache rows cost
-    nothing.  Attention-only decoder stacks, GQA attention only (the
-    engine gates on ``supports_paged_decode``)."""
+    nothing.  Attention-only decoder stacks (the engine gates on
+    ``supports_paged_decode``); MLA writes and reads its latent lines."""
     from repro.models.attention import PagedDecode
     return decode_step(cfg, params, tokens, state, t,
                        long_context=long_context,

@@ -11,8 +11,10 @@ paged decode carries the stacked K/V whole through the scan, see
   attention KV : k,v          (B, KVH, W, HD)    W = cache window capacity
                  (head-major: one head's lines are contiguous, the tile
                  the paged decode kernel DMAs)
-  MLA latent   : c_kv         (B, W, kv_lora_rank)
-                 k_rope       (B, W, qk_rope_head_dim)
+  MLA latent   : c_kv         (B, W, mla_line_width)
+                 (one line per token: the normed latent, the roped key
+                 shared by every head, zeros to a whole number of
+                 128-lane tiles — see ``mla_line_width``)
   mamba        : conv         (B, d_conv, d_in)
                  ssm          (B, d_in, d_state)
   mlstm        : C            (B, H, DK, DV)
@@ -40,7 +42,7 @@ KV_HEAD_AXIS, KV_LINE_AXIS = 1, 2
 # reads these tables.
 #: KV line (cache window) axis of the per-token caches
 LINE_AXIS = {"k": KV_LINE_AXIS + 1, "v": KV_LINE_AXIS + 1,
-             "c_kv": 2, "k_rope": 2}
+             "c_kv": 2}
 #: sequence axis: the per-token caches' lines, the cross caches' source
 SEQ_AXIS = dict(LINE_AXIS, xk=2, xv=2)
 #: KV-head axis of the GQA caches
@@ -67,6 +69,15 @@ def stacked_pool_view(stack, block_lines: int):
     return stack.reshape(R * B, KVH, W // block_lines, block_lines, HD)
 
 
+def stacked_latent_pool_view(stack, block_lines: int):
+    """A segment's stacked MLA line leaf ``c_kv`` ``(R, B, W, D)`` as one
+    block pool of ``R * B`` pages ``(R * B, W/block_lines, block_lines,
+    D)`` (a reshape, no copy), numbered as :func:`stacked_pool_view`
+    numbers K/V blocks."""
+    R, B, W, D = stack.shape
+    return stack.reshape(R * B, W // block_lines, block_lines, D)
+
+
 def line_index(slot, pos, axis: int) -> tuple:
     """Index of lines ``pos`` (array or slice) of batch row ``slot`` in a
     stacked leaf whose line axis is ``axis``."""
@@ -91,13 +102,22 @@ def cache_capacity(cfg: ModelConfig, seq_len: int, long_context: bool) -> int:
     return min(win, seq_len)
 
 
+def mla_line_width(cfg: ModelConfig) -> int:
+    """Width of an MLA cache line: ``kv_lora_rank + qk_rope_head_dim``
+    rounded up to whole 128-lane tiles (DeepSeek-V3: 512 + 64 -> 640).
+    A TPU lays out a leaf whose last axis is not a whole number of lanes
+    with its lines minor (no padding, but not the ``(lines, width)``
+    tiles the paged kernel DMAs), so every decode program would
+    relayout the whole stack; the pad keeps the line axis second-minor.
+    Accounting (``core.kvbytes``) counts the unpadded line."""
+    m = cfg.mla
+    return -(-(m.kv_lora_rank + m.qk_rope_head_dim) // 128) * 128
+
+
 def init_attn_kv(cfg: ModelConfig, batch: int, capacity: int, dtype):
     if cfg.attention_kind == "mla":
-        m = cfg.mla
-        return {
-            "c_kv": jnp.zeros((batch, capacity, m.kv_lora_rank), dtype),
-            "k_rope": jnp.zeros((batch, capacity, m.qk_rope_head_dim), dtype),
-        }
+        return {"c_kv": jnp.zeros((batch, capacity, mla_line_width(cfg)),
+                                  dtype)}
     return {
         "k": jnp.zeros((batch, cfg.num_kv_heads, capacity, cfg.head_dim), dtype),
         "v": jnp.zeros((batch, cfg.num_kv_heads, capacity, cfg.head_dim), dtype),

@@ -162,11 +162,10 @@ class InstanceEngine:
 
     @property
     def supports_paged_decode(self) -> bool:
-        """Paged decode gathers per-head K/V line blocks: attention-only
-        decoder stacks with GQA attention (MLA decodes through the
-        absorbed latent path; recurrent blocks carry no line-indexed
-        cache to gather)."""
-        return self._attn_only and self.cfg.attention_kind == "gqa"
+        """Paged decode gathers line blocks of the per-token caches (GQA
+        K/V or the MLA latent): attention-only decoder stacks (recurrent
+        blocks carry no line-indexed cache to gather)."""
+        return self._attn_only
 
     @property
     def state(self):

@@ -109,6 +109,19 @@ def test_sharded_tokens_bit_identical(mesh8, setup, tp):
         f"tp={tp} sharded decode diverged from single-device greedy")
 
 
+@pytest.mark.parametrize("arch", ["arctic-480b", "deepseek-v3-671b"])
+def test_moe_serves_on_a_mesh_as_on_one_device(mesh8, arch):
+    """Routed experts split over the model axis (the psum and a2a
+    strategies, with room for every token) serve the tokens one device
+    serves with all experts held."""
+    cfg = get_config(arch).reduced()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    base = _generate(cfg, params, mesh=None)
+    (sl,) = carve_slices(2, n_instances=1)
+    assert sl.model_axis_for(cfg) == "model"
+    assert _generate(cfg, params, mesh=sl) == base
+
+
 def test_indivisible_width_replicates_and_matches(mesh8, setup):
     cfg, params = setup
     base = _generate(cfg, params, mesh=None)

@@ -12,9 +12,10 @@ from repro.models.moe import (_capacity, _ranks_of, _route, init_moe,
                               moe_forward)
 
 
-def naive_moe(params, x2, top_k):
+def naive_moe(cfg, params, x2):
     """Dense reference: every expert on every token, mix by gates."""
-    gates, eidx, _ = _route(x2, params["router"], top_k)
+    gates, eidx, _ = _route(cfg.moe, x2, params["router"],
+                            params.get("router_bias"))
     h = swiglu(jnp.einsum("td,edf->tef", x2, params["w_gate"]),
                jnp.einsum("td,edf->tef", x2, params["w_up"]))
     y_all = jnp.einsum("tef,efd->ted", h, params["w_down"])  # (T,E,d)
@@ -34,7 +35,7 @@ def test_moe_matches_dense_reference(arch, rng_key):
     x = jax.random.normal(jax.random.fold_in(rng_key, 2),
                           (1, T, cfg.d_model))
     y, aux = moe_forward(cfg, routed, x)
-    exp = naive_moe(routed, x[0], cfg.moe.top_k)
+    exp = naive_moe(cfg, routed, x[0])
     err = float(jnp.abs(y[0] - exp.astype(y.dtype)).max())
     assert err < 1e-4, err
     assert float(aux) > 0

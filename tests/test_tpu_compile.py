@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.models import decode_multi, init_params, init_state
 from repro.models.attention import set_kernel_backend
+from repro.models.state import mla_line_width
 from repro.kernels.decode_attention import (decode_attention_pallas,
                                             paged_decode_attention_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -111,50 +112,42 @@ def _computations(text):
     return comps
 
 
-@pytest.mark.parametrize("arch,layers", [
-    ("starcoder2-3b", 2), ("phi3-medium-14b", 2),
-    ("starcoder2-3b", 30), ("phi3-medium-14b", 10)])
-def test_paged_decode_writes_kv_in_place_for_v5e(one_chip, arch, layers):
-    """The served ``decode_multi`` (pallas backend, 8 slots x 4,096
-    lines, ``block_lines`` 16) changes one line per row per layer, so no
-    operation whose result is a layer's or the stack's K/V may copy or
-    relayout it: no ``copy``, no ``copy*`` fusion, no scatter.  The
-    donated state is aliased to the output.  (A compile-time prefetch of
-    a stack small enough for the chip's on-core memory is an async
-    ``copy-start``/``copy-done`` into that memory, not a relayout; at 2
-    layers of starcoder2-3b the compiler makes one.)"""
-    cfg = get_config(arch)
-    cfg = dataclasses.replace(cfg, num_layers=layers,
-                              block_pattern=("attn",) * layers)
-    kvh, rows, steps, bl = cfg.num_kv_heads, 4, 2, 16
+def _decode_multi_text(cfg, sharding, rows=4, steps=2, bl=16):
+    """The v5e program text of the served ``decode_multi`` (pallas
+    backend, 8 slots x 4,096 lines, ``block_lines`` 16, donated state)."""
 
     def described(tree):
         return jax.tree_util.tree_map(
-            lambda a: _spec(a.shape, a.dtype, one_chip), tree)
+            lambda a: _spec(a.shape, a.dtype, sharding), tree)
 
     params = described(jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg)))
     state = described(jax.eval_shape(lambda: init_state(cfg, SLOTS, CAP)))
-    rows_i32 = _spec((rows,), jnp.int32, one_chip)
+    rows_i32 = _spec((rows,), jnp.int32, sharding)
     fn = jax.jit(functools.partial(decode_multi, cfg, block_lines=bl),
                  donate_argnums=(2,))
     set_kernel_backend("pallas")
     try:
-        text = fn.lower(
-            params, _spec((rows, 1), jnp.int32, one_chip), state, rows_i32,
-            rows_i32, _spec((rows, CAP // bl), jnp.int32, one_chip),
-            rows_i32, _spec((steps, 2), jnp.uint32, one_chip)
+        return fn.lower(
+            params, _spec((rows, 1), jnp.int32, sharding), state, rows_i32,
+            rows_i32, _spec((rows, CAP // bl), jnp.int32, sharding),
+            rows_i32, _spec((steps, 2), jnp.uint32, sharding)
         ).compile().as_text()
     finally:
         set_kernel_backend("auto")
-    assert "%paged_decode." in text
 
-    kv = rf"bf16\[(?:\d+,)?{SLOTS},{kvh},{CAP},{cfg.head_dim}\]"
+
+def _assert_lines_in_place(text, leaf: str, n_leaves: int):
+    """No operation whose result has the shape ``leaf`` (a regex of a
+    per-token cache layer or stack) copies or relayouts it — no
+    ``copy``, no ``copy*`` fusion, no scatter — and each of the
+    ``n_leaves`` such stacks the program takes in is aliased to an
+    output."""
     comps = _computations(text)
     bad = []
     for lines in comps.values():
         for line in lines:
-            m = re.match(rf"\s*(?:ROOT )?%(\S+) = {kv}\S* ([\w-]+)\(", line)
+            m = re.match(rf"\s*(?:ROOT )?%(\S+) = {leaf}\S* ([\w-]+)\(", line)
             if not m:
                 continue
             name, op = m.groups()
@@ -167,9 +160,46 @@ def test_paged_decode_writes_kv_in_place_for_v5e(one_chip, arch, layers):
                 bad.append(f"{name} = {op}")
     assert not bad, bad
 
-    # every K/V stack the program takes in is aliased to an output
-    kv_params = {int(n) for n in re.findall(
-        rf"= {kv}\S* parameter\((\d+)\)", "\n".join(comps["ENTRY"]))}
+    params = {int(n) for n in re.findall(
+        rf"= {leaf}\S* parameter\((\d+)\)", "\n".join(comps["ENTRY"]))}
     alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
     aliased = {int(n) for n in re.findall(r"\((\d+), \{\}", alias.group(1))}
-    assert len(kv_params) == 2 and kv_params <= aliased
+    assert len(params) == n_leaves and params <= aliased
+
+
+@pytest.mark.parametrize("arch,layers", [
+    ("starcoder2-3b", 2), ("phi3-medium-14b", 2),
+    ("starcoder2-3b", 30), ("phi3-medium-14b", 10)])
+def test_paged_decode_writes_kv_in_place_for_v5e(one_chip, arch, layers):
+    """The served ``decode_multi`` changes one line per row per layer, so
+    no operation whose result is a layer's or the stack's K/V may copy
+    or relayout it, and the donated state is aliased to the output.  (A
+    compile-time prefetch of a stack small enough for the chip's on-core
+    memory is an async ``copy-start``/``copy-done`` into that memory,
+    not a relayout; at 2 layers of starcoder2-3b the compiler makes
+    one.)"""
+    cfg = get_config(arch)
+    cfg = dataclasses.replace(cfg, num_layers=layers,
+                              block_pattern=("attn",) * layers)
+    text = _decode_multi_text(cfg, one_chip)
+    assert "%paged_decode." in text
+    _assert_lines_in_place(
+        text, rf"bf16\[(?:\d+,)?{SLOTS},{cfg.num_kv_heads},{CAP},"
+              rf"{cfg.head_dim}\]", 2)
+
+
+def test_paged_mla_decode_writes_latent_in_place_for_v5e(one_chip):
+    """DeepSeek-V3 at published widths, 2 layers (the dense one and an
+    expert layer holding 8 of 256 experts, so two segments): the decode
+    program runs the ``paged_mla_decode`` kernel, writes its lines
+    (latent 512, roped key 64, padded to 640) in place and aliases the
+    two stacks it takes in."""
+    cfg = get_config("deepseek-v3-671b")
+    cfg = dataclasses.replace(
+        cfg, num_layers=2, block_pattern=("attn",) * 2, mtp_depth=0,
+        moe=dataclasses.replace(cfg.moe, first_dense_layers=1,
+                                experts_held=8))
+    text = _decode_multi_text(cfg, one_chip)
+    assert "%paged_mla_decode." in text
+    width = mla_line_width(cfg)
+    _assert_lines_in_place(text, rf"bf16\[(?:\d+,)?{SLOTS},{CAP},{width}\]", 2)
